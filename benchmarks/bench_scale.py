@@ -147,13 +147,16 @@ def _traced_overhead(n, pol, repeats=3):
 
 def _device_sweep(device_counts, n_ues, n_cells):
     """One subprocess per point: the forced-device flag must be set
-    before jax initializes, so each count needs a fresh interpreter."""
+    before jax initializes, so each count needs a fresh interpreter.
+    The children run on forced HOST devices (JAX_PLATFORMS=cpu, never the
+    parent's accelerator, which the parent holds): their rows say
+    ``platform: cpu`` and measure host-device sharding, not chip scaling."""
     rows = []
     for nd in device_counts:
         env = dict(os.environ)
         env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={nd} "
                             + env.get("XLA_FLAGS", "")).strip()
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = "cpu"
         out = subprocess.run(
             [sys.executable, "-m", "benchmarks.bench_scale",
              "--device-worker", str(nd), str(n_ues), str(n_cells)],
@@ -162,7 +165,7 @@ def _device_sweep(device_counts, n_ues, n_cells):
             raise RuntimeError(f"device worker ({nd}) failed:\n{out.stderr}")
         row = json.loads(out.stdout.strip().splitlines()[-1])
         rows.append(row)
-        print(f"  devices={row['n_devices']}: "
+        print(f"  {row['platform']} devices={row['n_devices']}: "
               f"{row['s_per_slot'] * 1e3:7.1f} ms/slot "
               f"({n_ues} UEs / {n_cells} cells)")
     return rows
@@ -188,7 +191,8 @@ def _device_worker(n_dev, n_ues, n_cells):
     for _ in range(n_slots):
         mac.serve_slot_arrays(batches, rngs)
     dt = (time.perf_counter() - t0) / n_slots
-    print(json.dumps({"n_devices": n_dev, "n_ues": n_ues,
+    print(json.dumps({"platform": jax.devices()[0].platform,
+                      "n_devices": n_dev, "n_ues": n_ues,
                       "n_cells": n_cells, "s_per_slot": dt}))
 
 

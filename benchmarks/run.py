@@ -24,6 +24,8 @@ def main() -> int:
                          "respect it")
     args = ap.parse_args()
 
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_adaptive, bench_cell, bench_chaos,
                             bench_chaos_corr, bench_compression, bench_dupf,
                             bench_e2e_delay, bench_energy_breakdown,

@@ -23,9 +23,11 @@ from repro.core.splitting import SERVER_ONLY, UE_ONLY
 from repro.core.throughput import train_estimator
 from repro.data.video import SyntheticVideo, VideoConfig
 from repro.models import swin as SW
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--frames", type=int, default=40)
     ap.add_argument("--narrowband", action="store_true")

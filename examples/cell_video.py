@@ -53,9 +53,11 @@ from repro.core.ran import (POLICIES, MultiCell, RanCell, RanConfig,
                             make_policy)
 from repro.data.video import SyntheticVideo, VideoConfig
 from repro.models import swin as SW
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--ues", type=int, default=6)
     ap.add_argument("--frames", type=int, default=12)

@@ -15,9 +15,11 @@ from repro.core.channel import dupf_path, iq_spectrogram, observe_kpms
 from repro.core.throughput import train_estimator
 from repro.data.video import SyntheticVideo, VideoConfig
 from repro.models import swin as SW
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     # 1. an unmodified Swin-T detector (reduced size for CPU)
     cfg = reduced()
     params = SW.init(cfg, jax.random.PRNGKey(0))

@@ -401,6 +401,9 @@ class CellSimulator:
         if self.engine not in ("python", "vectorized"):
             raise ValueError(f"unknown MAC engine {self.engine!r}; "
                              f"choose 'python' or 'vectorized'")
+        if self.engine == "vectorized" and self.ran is not None:
+            from repro.core.ran_vec import require_f64_bitcast_backend
+            require_f64_bitcast_backend()
         self.narrowband = np.broadcast_to(
             np.asarray(self.narrowband, bool), (self.n_ues,)).copy()
         if isinstance(self.ran, MultiCell):

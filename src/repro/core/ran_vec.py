@@ -27,7 +27,7 @@ shape, not approximation:
 
 Exactness discipline (why the odd-looking bits exist):
 
-  * Everything runs in float64 under ``jax.experimental.enable_x64`` --
+  * Everything runs in float64 under ``jax.enable_x64(True)`` --
     scoped, so the f32 model/kernel stack in the same process is
     untouched.
   * XLA:CPU contracts ``a*b + c`` into an FMA, which rounds once where
@@ -561,8 +561,23 @@ def _chunk_schedule(n_lanes: int):
 
 
 def _x64():
-    from jax.experimental import enable_x64
-    return enable_x64()
+    import jax
+    return jax.enable_x64(True)
+
+
+def require_f64_bitcast_backend():
+    """Refuse a TPU backend up front.  ``_seal`` bitcasts f64 <-> s64, and
+    the TPU compiler's X64 rewriter refuses that op ("UNIMPLEMENTED ...
+    bitcast-convert"), so no scan kernel here compiles for the chip.
+    Failing at construction keeps a run from quietly moving the MAC to
+    another device; ``engine='python'`` runs everywhere."""
+    import jax
+    if jax.default_backend() == "tpu":
+        raise NotImplementedError(
+            "the vectorized MAC engine cannot run on a TPU backend: its "
+            "f64 kernels bitcast f64<->s64 (ran_vec._seal), which the TPU "
+            "compiler refuses (UNIMPLEMENTED bitcast-convert in its X64 "
+            "rewrite); use engine='python'")
 
 
 @dataclass
@@ -577,6 +592,7 @@ class VecRanCell:
     grant_trace: List[Tuple[int, Tuple]] = field(default_factory=list)
 
     def __post_init__(self):
+        require_f64_bitcast_backend()
         self._rr_ptr = 0
         self._pf_avg = np.zeros(0)
         self._tape = _UniformTape()

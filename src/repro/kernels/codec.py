@@ -36,6 +36,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.kernels.quant import smem_scale_spec
+
 INT8_MAX = 127.0
 # same explicit reciprocal multiply as kernels/quant.py: bitwise-stable
 # scales across eager/jit/interpret keep this stream on the exact quant
@@ -56,20 +58,30 @@ def _encode_kernel(x_ref, q_ref, s_ref, *, delta: bool):
         q_ref[...] = ((q - prev) % 256).astype(jnp.uint8)
     else:
         q_ref[...] = q.astype(jnp.int8)
-    s_ref[0] = scale
+    s_ref[0, 0, 0] = scale
+
+
+def _row_cumsum(x):
+    """Inclusive int32 prefix sum down the rows (Hillis-Steele: log2(rows)
+    shifted adds).  Mosaic has no cumsum; integer adds are exact, so this
+    equals ``jnp.cumsum(x, axis=0)`` bit for bit."""
+    k = 1
+    while k < x.shape[0]:
+        x = x + jnp.pad(x[:-k], ((k, 0), (0, 0)))
+        k *= 2
+    return x
 
 
 def _decode_kernel(q_ref, s_ref, o_ref, *, delta: bool):
     if delta:
-        acc = jnp.cumsum(q_ref[...].astype(jnp.int32), axis=0) % 256
+        acc = _row_cumsum(q_ref[...].astype(jnp.int32)) % 256
         q = acc - jnp.where(acc > 127, 256, 0)          # back to signed grid
     else:
         q = q_ref[...].astype(jnp.int32)
-    o_ref[...] = q.astype(jnp.float32) * s_ref[0]
+    o_ref[...] = q.astype(jnp.float32) * s_ref[0, 0, 0]
 
 
-def codec_encode_pallas(flat, *, block: int, delta: bool,
-                        interpret: bool = True):
+def codec_encode_pallas(flat, *, block: int, delta: bool, interpret: bool):
     """flat: (total,) with total % block == 0 (caller packs + pads leaves).
 
     Returns (stream (total,) uint8|int8, scales (nb,) f32).  Quantization
@@ -87,20 +99,20 @@ def codec_encode_pallas(flat, *, block: int, delta: bool,
         in_specs=[pl.BlockSpec((rows, LANES), lambda i: (i, 0))],
         out_specs=[
             pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (i,)),
+            smem_scale_spec(),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((nb * rows, LANES),
                                  jnp.uint8 if delta else jnp.int8),
-            jax.ShapeDtypeStruct((nb,), jnp.float32),
+            jax.ShapeDtypeStruct((nb, 1, 1), jnp.float32),
         ],
         interpret=interpret,
     )(xb)
-    return q.reshape(-1), s
+    return q.reshape(-1), s.reshape(nb)
 
 
 def codec_decode_pallas(stream, scales, *, block: int, delta: bool,
-                        interpret: bool = True):
+                        interpret: bool):
     """Inverse of codec_encode_pallas.  Returns (total,) f32 (callers slice
     per-leaf segments back out and cast to the leaf dtype)."""
     assert block % LANES == 0
@@ -112,10 +124,10 @@ def codec_decode_pallas(stream, scales, *, block: int, delta: bool,
         grid=(nb,),
         in_specs=[
             pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (i,)),
+            smem_scale_spec(),
         ],
         out_specs=pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nb * rows, LANES), jnp.float32),
         interpret=interpret,
-    )(qb, scales)
+    )(qb, scales.reshape(nb, 1, 1))
     return o.reshape(-1)

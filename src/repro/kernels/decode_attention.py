@@ -62,7 +62,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref, acc_ref,
 
 
 def decode_attention_pallas(q, k, v, kv_len, *, block_kv: int = 512,
-                            interpret: bool = True):
+                            interpret: bool):
     """q: (B,1,H,hd); k,v: (B,S,KV,hd); kv_len: (B,) -> (B,1,H,hd)."""
     B, _, H, hd = q.shape
     _, S, KV, _ = k.shape

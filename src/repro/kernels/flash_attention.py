@@ -77,7 +77,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            block_q: int = 128, block_kv: int = 128,
-                           interpret: bool = True):
+                           interpret: bool):
     """q: (B,S,H,hd); k,v: (B,Skv,KV,hd) -> (B,S,H,hd)."""
     B, Sq, H, hd = q.shape
     _, Skv, KV, _ = k.shape

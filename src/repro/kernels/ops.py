@@ -1,8 +1,11 @@
 """Public kernel entry points.
 
-Each op dispatches to the Pallas TPU kernel (interpret=True when no TPU is
-present, so the same code validates on CPU) and pads inputs to
-hardware-aligned tiles.  ``ref.py`` holds the pure-jnp oracles the tests
+Each op dispatches to the Pallas TPU kernel and pads inputs to
+hardware-aligned tiles.  This module is the one place that decides how a
+kernel runs: compiled on a TPU, and off-TPU either the interpreter
+(``quantize``/``dequantize``/``window_attention``) or a bitwise-identical
+jnp mirror (the codec pair and the attention kernels below).  The kernel
+wrappers take ``interpret`` without a default.  ``ref.py`` holds the pure-jnp oracles the tests
 compare against.
 """
 from __future__ import annotations

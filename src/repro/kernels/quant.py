@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 INT8_MAX = 127.0
 # Explicit reciprocal multiply for the scale: XLA rewrites the constant
@@ -31,20 +32,29 @@ LANES = 128
 BLOCK_ROWS = 64          # (64, 128) fp32 tile = 32 KiB VMEM per buffer
 
 
+def smem_scale_spec():
+    """BlockSpec for one f32 scale per grid step, held in SMEM as an
+    (nb, 1, 1) array.  A rank-1 (1,) block is refused by the TPU lowering
+    (rank-1 blocks must span 128 lanes); a per-step scalar belongs in
+    SMEM.  Shared with kernels/codec.py."""
+    return pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0),
+                        memory_space=pltpu.SMEM)
+
+
 def _quant_kernel(x_ref, q_ref, s_ref):
     x = x_ref[...].astype(jnp.float32)                  # (BLOCK_ROWS, LANES)
     absmax = jnp.max(jnp.abs(x))
     scale = jnp.where(absmax > 0, absmax * INV_INT8_MAX, 1.0)
     q = jnp.clip(jnp.round(x / scale), -INT8_MAX, INT8_MAX)
     q_ref[...] = q.astype(jnp.int8)
-    s_ref[0] = scale
+    s_ref[0, 0, 0] = scale
 
 
 def _dequant_kernel(q_ref, s_ref, o_ref):
-    o_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[0]
+    o_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[0, 0, 0]
 
 
-def quant_pallas(x, *, block: int = BLOCK_ROWS * LANES, interpret: bool = True):
+def quant_pallas(x, *, block: int = BLOCK_ROWS * LANES, interpret: bool):
     """x: arbitrary shape.  Returns (q int8 (nb, block), scales (nb,), n)."""
     assert block % LANES == 0
     rows = block // LANES
@@ -65,18 +75,18 @@ def quant_pallas(x, *, block: int = BLOCK_ROWS * LANES, interpret: bool = True):
         in_specs=[pl.BlockSpec((rows, LANES), lambda i: (i, 0))],
         out_specs=[
             pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (i,)),
+            smem_scale_spec(),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((nb * rows, LANES), jnp.int8),
-            jax.ShapeDtypeStruct((nb,), jnp.float32),
+            jax.ShapeDtypeStruct((nb, 1, 1), jnp.float32),
         ],
         interpret=interpret,
     )(xb)
-    return q.reshape(nb, block), s, n
+    return q.reshape(nb, block), s.reshape(nb), n
 
 
-def dequant_pallas(q, s, n, shape, dtype=jnp.float32, *, interpret: bool = True):
+def dequant_pallas(q, s, n, shape, dtype=jnp.float32, *, interpret: bool):
     """Inverse of quant_pallas."""
     nb, block = q.shape
     if nb == 0:
@@ -88,10 +98,10 @@ def dequant_pallas(q, s, n, shape, dtype=jnp.float32, *, interpret: bool = True)
         grid=(nb,),
         in_specs=[
             pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (i,)),
+            smem_scale_spec(),
         ],
         out_specs=pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nb * rows, LANES), jnp.float32),
         interpret=interpret,
-    )(qb, s)
+    )(qb, s.reshape(nb, 1, 1))
     return o.reshape(-1)[:n].reshape(shape).astype(dtype)

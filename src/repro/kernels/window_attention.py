@@ -21,9 +21,12 @@ original coordinates.  The grid walks window-row bands; the H-axis roll
 never materializes in HBM -- each step assembles its rolled band from two
 consecutive original bands (modular index maps) and a VMEM carry holds
 the ``shift`` rows that cross the band boundary on the way back out, so
-every step writes one complete original-coordinate output band.
+every step writes one complete original-coordinate output band.  Inside
+a band the attention runs one head at a time (``_window_core``): Mosaic's
+matmul takes a single batch dim, here the band's windows.
 ``fused_window_attention_jnp`` is the bitwise-identical pure-jnp mirror
-ops.py dispatches to off-TPU (tests pin kernel == mirror exactly).
+ops.py dispatches to off-TPU: it calls ``_window_core`` verbatim over all
+windows at once (tests pin kernel == mirror exactly).
 """
 from __future__ import annotations
 
@@ -54,7 +57,7 @@ def _window_kernel(q_ref, k_ref, v_ref, b_ref, m_ref, o_ref, *, sm_scale):
     o_ref[0, :, 0, :] = o.astype(o_ref.dtype)
 
 
-def window_attention_pallas(q, k, v, bias, mask, *, interpret: bool = True):
+def window_attention_pallas(q, k, v, bias, mask, *, interpret: bool):
     """q,k,v: (nB, W2P, nh, hd); bias: (nh, W2P, W2P);
     mask: (nB, W2P, W2P) int8.  W2P and hd should be 64/128-aligned
     (ops.py pads).  Returns (nB, W2P, nh, hd)."""
@@ -80,6 +83,35 @@ def window_attention_pallas(q, k, v, bias, mask, *, interpret: bool = True):
 # fused whole-layer kernel: partition + roll + attention + un-partition
 # ---------------------------------------------------------------------------
 
+def _window_core(x, bias, mask, *, n_heads: int, sm_scale: float):
+    """Biased/masked softmax attention over pre-partitioned windows.
+
+    x: (n, W2P, 3C) packed qkv, one padded window per leading index;
+    bias: (nh, W2P, W2P) f32; mask: (n, W2P, W2P) int8 (1 = attend).
+    Returns (n, W2P, C) f32.  One head at a time, so every matmul has a
+    single batch dim (the window) -- the only form Mosaic's ``tpu.matmul``
+    lowers.  Shared verbatim by the kernel body and the jnp mirror.
+    """
+    C = x.shape[-1] // 3
+    hd = C // n_heads
+    x = x.astype(jnp.float32)
+    keep = mask.astype(jnp.int32) > 0    # v5e has no int8 vector compare
+    outs = []
+    for h in range(n_heads):
+        q = x[..., h * hd:(h + 1) * hd] * sm_scale
+        k = x[..., C + h * hd:C + (h + 1) * hd]
+        v = x[..., 2 * C + h * hd:2 * C + (h + 1) * hd]
+        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32)
+        s = jnp.where(keep, s + bias[h], NEG_INF)    # (n, W2P, W2P)
+        m = s.max(axis=-1, keepdims=True)
+        p = jnp.exp(s - m)
+        p = p / p.sum(axis=-1, keepdims=True)
+        outs.append(jax.lax.dot_general(p, v, (((2,), (1,)), ((0,), (0,))),
+                                        preferred_element_type=jnp.float32))
+    return jnp.concatenate(outs, axis=-1)
+
+
 def _band_attention(band, bias, mask, *, window: int, n_heads: int,
                     w2: int, W2P: int, sm_scale: float):
     """Windowed attention over ONE window-row band.
@@ -87,35 +119,17 @@ def _band_attention(band, bias, mask, *, window: int, n_heads: int,
     band: (window, Wp, 3C) packed qkv in image layout (already rolled when
     the layer shifts); bias: (nh, W2P, W2P) f32; mask: (nww, W2P, W2P)
     int8.  Partitions the band into its nww windows, pads w2 -> W2P, runs
-    the biased/masked softmax, and un-partitions back to (window, Wp, C)
-    f32.  Shared verbatim by the kernel body and the jnp mirror so the op
-    sequence (and therefore every last bit) is identical on both paths.
+    ``_window_core``, and un-partitions back to (window, Wp, C) f32.
     """
     Wp = band.shape[1]
     C = band.shape[2] // 3
     nww = Wp // window
-    hd = C // n_heads
     x = band.reshape(window, nww, window, 3 * C)
     x = x.transpose(1, 0, 2, 3).reshape(nww, w2, 3 * C)
     if W2P != w2:
         x = jnp.pad(x, ((0, 0), (0, W2P - w2), (0, 0)))
-    q = x[..., :C].reshape(nww, W2P, n_heads, hd).transpose(0, 2, 1, 3)
-    k = x[..., C:2 * C].reshape(nww, W2P, n_heads, hd).transpose(0, 2, 1, 3)
-    v = x[..., 2 * C:].reshape(nww, W2P, n_heads, hd).transpose(0, 2, 1, 3)
-    q = q.astype(jnp.float32) * sm_scale
-    k = k.astype(jnp.float32)
-    v = v.astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((3,), (3,)), ((0, 1), (0, 1))),
-                            preferred_element_type=jnp.float32)
-    s = s + bias[None].astype(jnp.float32)          # (nww, nh, W2P, W2P)
-    s = jnp.where(mask[:, None] > 0, s, NEG_INF)
-    m = s.max(axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    p = p / p.sum(axis=-1, keepdims=True)
-    o = jax.lax.dot_general(p, v, (((3,), (2,)), ((0, 1), (0, 1))),
-                            preferred_element_type=jnp.float32)
-    o = o.transpose(0, 2, 1, 3).reshape(nww, W2P, C)[:, :w2]
-    o = o.reshape(nww, window, window, C).transpose(1, 0, 2, 3)
+    o = _window_core(x, bias, mask, n_heads=n_heads, sm_scale=sm_scale)
+    o = o[:, :w2].reshape(nww, window, window, C).transpose(1, 0, 2, 3)
     return o.reshape(window, Wp, C)
 
 
@@ -154,7 +168,7 @@ def _fused_kernel_shift(a_ref, b_ref, bias_ref, mask_ref, o_ref, carry_ref, *,
 
 
 def fused_window_attention_pallas(qkv, bias, mask, *, window: int, shift: int,
-                                  n_heads: int, interpret: bool = True):
+                                  n_heads: int, interpret: bool):
     """One-launch Swin window attention over a whole feature map.
 
     qkv: (B, Hp, Wp, 3C) packed projection in ORIGINAL image coordinates
@@ -167,7 +181,10 @@ def fused_window_attention_pallas(qkv, bias, mask, *, window: int, shift: int,
     out.  shift > 0 runs (B, nwh + 1) steps with the carry scheme above
     (band nwh-1 is visited twice; the extra step is the pipeline drain).
     VMEM per step: two input bands + one output band + the (shift, Wp, C)
-    carry -- ~6.5 MB double-buffered at the full config's stage 0.
+    carry.  At the published Swin-T's stage 0 (Wp=203, 3C=288) the TPU
+    compiler accepts the kernel down to a scoped-VMEM limit of 6.7 MiB
+    unshifted and 7.9 MiB shifted (v5e, compiled without a chip), under
+    the 16 MiB default.
     """
     B, Hp, Wp, C3 = qkv.shape
     C = C3 // 3
@@ -227,18 +244,18 @@ def fused_window_attention_jnp(qkv, bias, mask, *, window: int, shift: int,
     """Bitwise mirror of ``fused_window_attention_pallas`` in plain jnp.
 
     Same inputs/outputs.  The roll/partition steps are pure permutations
-    and the per-band math is ``_band_attention`` verbatim (vectorized over
-    the batch x band axis -- each window's reductions keep the kernel's
-    exact shapes and order), so the dispatch switch in ops.py cannot
-    change a single bit (tests/test_kernels.py pins kernel == mirror).
+    and the per-window math is ``_window_core`` verbatim (over every
+    window of the map at once -- each window's reductions keep the
+    kernel's exact shapes and order), so the dispatch switch in ops.py
+    cannot change a single bit (tests/test_kernels.py pins kernel ==
+    mirror).
     """
     B, Hp, Wp, C3 = qkv.shape
     C = C3 // 3
     w2 = window * window
     nwh, nww = Hp // window, Wp // window
     W2P = mask.shape[-1]
-    hd = C // n_heads
-    sm_scale = 1.0 / math.sqrt(hd)
+    sm_scale = 1.0 / math.sqrt(C // n_heads)
     x = qkv
     if shift:
         x = jnp.roll(x, (-shift, -shift), axis=(1, 2))
@@ -246,27 +263,11 @@ def fused_window_attention_jnp(qkv, bias, mask, *, window: int, shift: int,
     x = x.reshape(B * nwh * nww, w2, C3)
     if W2P != w2:
         x = jnp.pad(x, ((0, 0), (0, W2P - w2), (0, 0)))
-    q = x[..., :C].reshape(-1, W2P, n_heads, hd).transpose(0, 2, 1, 3)
-    k = x[..., C:2 * C].reshape(-1, W2P, n_heads, hd).transpose(0, 2, 1, 3)
-    v = x[..., 2 * C:].reshape(-1, W2P, n_heads, hd).transpose(0, 2, 1, 3)
-    q = q.astype(jnp.float32) * sm_scale
-    k = k.astype(jnp.float32)
-    v = v.astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((3,), (3,)), ((0, 1), (0, 1))),
-                            preferred_element_type=jnp.float32)
-    s = s + jnp.broadcast_to(bias.astype(jnp.float32)[None],
-                             (B * nwh * nww, n_heads, W2P, W2P))
     mflat = jnp.broadcast_to(mask.reshape(1, nwh * nww, W2P, W2P),
                              (B, nwh * nww, W2P, W2P)).reshape(-1, W2P, W2P)
-    s = jnp.where(mflat[:, None] > 0, s, NEG_INF)
-    m = s.max(axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    p = p / p.sum(axis=-1, keepdims=True)
-    o = jax.lax.dot_general(p, v, (((3,), (2,)), ((0, 1), (0, 1))),
-                            preferred_element_type=jnp.float32)
-    o = o.transpose(0, 2, 1, 3).reshape(-1, W2P, C)[:, :w2]
-    o = o.reshape(B, nwh, nww, window, window, C).transpose(0, 1, 3, 2, 4, 5)
-    o = o.reshape(B, Hp, Wp, C)
+    o = _window_core(x, bias, mflat, n_heads=n_heads, sm_scale=sm_scale)
+    o = o[:, :w2].reshape(B, nwh, nww, window, window, C)
+    o = o.transpose(0, 1, 3, 2, 4, 5).reshape(B, Hp, Wp, C)
     if shift:
         o = jnp.roll(o, (shift, shift), axis=(1, 2))
     return o.astype(qkv.dtype)

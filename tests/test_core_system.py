@@ -16,7 +16,7 @@ from repro.configs.swin_t_detection import CONFIG as SWIN_FULL
 
 @pytest.fixture(scope="module")
 def system():
-    return C.calibrate()          # cached after the first (expensive) run
+    return C.calibrate()          # reads the tracked payload table
 
 
 @pytest.fixture(scope="module")
@@ -26,6 +26,32 @@ def accounting_pipeline(system):
     return SplitInferencePipeline(
         plan=plan, system=system, codec=ActivationCodec(),
         controller=None, execute_model=False, seed=7)
+
+
+# -- tracked payload table -----------------------------------------------------
+
+def test_payload_table_raw_bytes_match_published_shapes():
+    """The tracked table's raw bytes are the published Swin-T boundary
+    tensors in f32 (and the uint8 image for server-only); compressed
+    bytes are what the int8+zlib codec measured on them."""
+    from repro.models import swin as SW
+    table = C.load_payload_table()
+    assert set(table) == {UE_ONLY, SERVER_ONLY, "split1", "split2", "split3",
+                          "split4"}
+    for l in range(1, 5):
+        row = table[f"split{l}"]
+        assert row["raw"] == SW.boundary_bytes(SWIN_FULL, l)
+        assert 0 < row["compressed"] < row["raw"]
+    n_img = SWIN_FULL.img_h * SWIN_FULL.img_w * 3
+    assert table[SERVER_ONLY] == {"raw": n_img, "compressed": n_img}
+    assert table[UE_ONLY] == {"raw": 0, "compressed": 0}
+
+
+def test_calibrate_reads_only_the_tracked_table(system):
+    table = C.load_payload_table()
+    assert system.raw_bytes == {k: v["raw"] for k, v in table.items()}
+    assert system.compressed_bytes == {k: v["compressed"]
+                                       for k, v in table.items()}
 
 
 # -- channel -------------------------------------------------------------------

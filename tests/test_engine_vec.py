@@ -181,3 +181,24 @@ def test_synthetic_city_partition():
     batches = synthetic_city(1000, 3, seed=1)
     assert len(batches) == 3
     assert sum(len(x["ue"]) for x in batches) == 1000
+
+
+def test_vectorized_engine_refuses_a_tpu_backend(monkeypatch):
+    """The scan kernels bitcast f64<->s64, which the TPU compiler refuses
+    (tests/test_tpu_compile.py shows it): on a TPU backend the engine
+    fails at construction instead of running anywhere else."""
+    import jax
+    from repro.core.ran_vec import VecRanCell
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(NotImplementedError, match="bitcast"):
+        CellSimulator(plan=SwinSplitPlan(SWIN_FULL, params=None),
+                      system=_system(), n_ues=2, engine="vectorized",
+                      ran=RanCell(policy=make_policy("edf"),
+                                  cfg=RanConfig()))
+    with pytest.raises(NotImplementedError, match="bitcast"):
+        VecRanCell.from_cell(RanCell(policy=make_policy("rr"),
+                                     cfg=RanConfig()))
+    # the python engine is untouched
+    CellSimulator(plan=SwinSplitPlan(SWIN_FULL, params=None),
+                  system=_system(), n_ues=2, engine="python",
+                  ran=RanCell(policy=make_policy("edf"), cfg=RanConfig()))
