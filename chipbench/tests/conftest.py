@@ -1,0 +1,11 @@
+import os
+import sys
+
+# the benchmark's tests run on the CPU and never load the TPU library
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+for p in (ROOT, os.path.join(ROOT, "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
