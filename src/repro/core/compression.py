@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.telemetry import host_span
 from repro.kernels import ops
 
 _INT8_MODES = ("int8", "int8_zlib", "int8_delta_zlib")
@@ -90,6 +91,9 @@ class CompressedPayload:
     ``mode`` records the codec mode the payload was produced with, so the
     receiver decodes it correctly even if its own codec was constructed
     with a different default (None = legacy payload, decoder's mode wins).
+    ``encode_s`` is this payload's share of the wall time of the
+    ``codec.encode`` span that produced it (the span over the frames it
+    encoded); it is not part of the payload's value.
     ``fused`` marks the single-stream layout: ``blobs``/``scales`` hold
     ONE entry covering every leaf, and ``meta[i].block_start`` locates
     leaf i's segment inside the stream.  ``delta_layout`` records which
@@ -104,6 +108,7 @@ class CompressedPayload:
     mode: Optional[str] = None
     fused: bool = False
     delta_layout: Optional[str] = None
+    encode_s: float = field(default=0.0, compare=False)
 
     @property
     def compressed_bytes(self) -> int:
@@ -261,6 +266,28 @@ def _segment_metas(leaves, block: int,
     return metas, raw, start
 
 
+def _to_host(stream, scales) -> Tuple[np.ndarray, np.ndarray]:
+    """The encoded stream and its scales, once ready on the device, in
+    one device-to-host transfer."""
+    with host_span("codec.wait"):
+        jax.block_until_ready((stream, scales))
+    with host_span("copy.codec_d2h", bytes=stream.nbytes + scales.nbytes):
+        return jax.device_get((stream, scales))
+
+
+def _to_device(stream: np.ndarray, scales: np.ndarray):
+    """The received stream and scales in one host-to-device transfer."""
+    with host_span("copy.codec_h2d", bytes=stream.nbytes + scales.nbytes):
+        return jax.device_put((stream, scales))
+
+
+def _inflate(blob: bytes) -> bytes:
+    with host_span("codec.zlib", bytes_in=len(blob)) as sp:
+        raw = zlib.decompress(blob)
+        sp.set(bytes_out=len(raw))
+    return raw
+
+
 @dataclass
 class ActivationCodec:
     """INT8+zlib codec with payload accounting.
@@ -297,11 +324,23 @@ class ActivationCodec:
                              f"lane width); got {self.quant_block}")
         return self.fused and self.mode in _INT8_MODES
 
+    def _deflate(self, buf: bytes) -> bytes:
+        """zlib of one payload's int8 bytes (mode 'int8' ships them as
+        they are)."""
+        if self.mode == "int8":
+            return buf
+        with host_span("codec.zlib", bytes_in=len(buf)) as sp:
+            blob = zlib.compress(buf, self.level)
+            sp.set(bytes_out=len(blob))
+        return blob
+
     # -- compress -----------------------------------------------------------
     def compress(self, tree) -> CompressedPayload:
-        if self._use_fused():
-            return self._compress_fused(tree)
-        return self._compress_legacy(tree)
+        with host_span("codec.encode", frames=1) as sp:
+            p = (self._compress_fused(tree) if self._use_fused()
+                 else self._compress_legacy(tree))
+        p.encode_s = sp.seconds
+        return p
 
     def _compress_fused(self, tree) -> CompressedPayload:
         leaves, treedef = jax.tree.flatten(tree)
@@ -309,12 +348,11 @@ class ActivationCodec:
         delta = self.mode == "int8_delta_zlib"
         stream, scales = _fused_encode_fn(
             self.quant_block, delta, self.delta_layout)(tuple(leaves))
-        stream, scales = jax.device_get((stream, scales))   # one transfer
+        stream, scales = _to_host(stream, scales)           # one transfer
         metas, raw, _ = _segment_metas(
             leaves, self.quant_block,
             record_delta=delta and self.delta_layout == "spatial")
-        buf = stream.tobytes()
-        blob = buf if self.mode == "int8" else zlib.compress(buf, self.level)
+        blob = self._deflate(stream.tobytes())
         return CompressedPayload([blob], [scales], metas, raw, treedef,
                                  mode=self.mode, fused=True,
                                  delta_layout=self.delta_layout if delta
@@ -384,20 +422,20 @@ class ActivationCodec:
             tree = producer(params, inputs)
             return self.compress(tree), tree
         delta = self.mode == "int8_delta_zlib"
-        tree, stream, scales = _fused_producer_encode_fn(
-            producer, self.quant_block, delta, self.delta_layout)(
-            params, inputs)
-        leaves, treedef = jax.tree.flatten(tree)
-        stream, scales = jax.device_get((stream, scales))   # one transfer
-        metas, raw, _ = _segment_metas(
-            leaves, self.quant_block,
-            record_delta=delta and self.delta_layout == "spatial")
-        buf = stream.tobytes()
-        blob = buf if self.mode == "int8" else zlib.compress(buf, self.level)
+        with host_span("codec.encode", frames=1) as sp:
+            tree, stream, scales = _fused_producer_encode_fn(
+                producer, self.quant_block, delta, self.delta_layout)(
+                params, inputs)
+            leaves, treedef = jax.tree.flatten(tree)
+            stream, scales = _to_host(stream, scales)       # one transfer
+            metas, raw, _ = _segment_metas(
+                leaves, self.quant_block,
+                record_delta=delta and self.delta_layout == "spatial")
+            blob = self._deflate(stream.tobytes())
         return (CompressedPayload([blob], [scales], metas, raw, treedef,
                                   mode=self.mode, fused=True,
                                   delta_layout=self.delta_layout if delta
-                                  else None),
+                                  else None, encode_s=sp.seconds),
                 tree)
 
     # -- batch-group compress (one launch across many payloads) -------------
@@ -412,41 +450,44 @@ class ActivationCodec:
         if not trees or len(trees) == 1 or not self._use_fused():
             return [self.compress(t) for t in trees]
         delta = self.mode == "int8_delta_zlib"
-        flat: List[Any] = []
-        per_tree = []
-        for t in trees:
-            leaves, treedef = jax.tree.flatten(t)
-            leaves = [jnp.asarray(x) for x in leaves]
-            per_tree.append((leaves, treedef))
-            flat.extend(leaves)
-        stream, scales = _fused_encode_fn(
-            self.quant_block, delta, self.delta_layout)(tuple(flat))
-        stream, scales = jax.device_get((stream, scales))
-        out, start = [], 0
-        for leaves, treedef in per_tree:
-            metas, raw, nb = _segment_metas(
-                leaves, self.quant_block,
-                record_delta=delta and self.delta_layout == "spatial")
-            buf = stream[start * self.quant_block:
-                         (start + nb) * self.quant_block].tobytes()
-            blob = (buf if self.mode == "int8"
-                    else zlib.compress(buf, self.level))
-            out.append(CompressedPayload(
-                [blob], [scales[start:start + nb].copy()], metas, raw,
-                treedef, mode=self.mode, fused=True,
-                delta_layout=self.delta_layout if delta else None))
-            start += nb
+        with host_span("codec.encode", frames=len(trees)) as sp:
+            flat: List[Any] = []
+            per_tree = []
+            for t in trees:
+                leaves, treedef = jax.tree.flatten(t)
+                leaves = [jnp.asarray(x) for x in leaves]
+                per_tree.append((leaves, treedef))
+                flat.extend(leaves)
+            stream, scales = _fused_encode_fn(
+                self.quant_block, delta, self.delta_layout)(tuple(flat))
+            stream, scales = _to_host(stream, scales)
+            out, start = [], 0
+            for leaves, treedef in per_tree:
+                metas, raw, nb = _segment_metas(
+                    leaves, self.quant_block,
+                    record_delta=delta and self.delta_layout == "spatial")
+                blob = self._deflate(
+                    stream[start * self.quant_block:
+                           (start + nb) * self.quant_block].tobytes())
+                out.append(CompressedPayload(
+                    [blob], [scales[start:start + nb].copy()], metas, raw,
+                    treedef, mode=self.mode, fused=True,
+                    delta_layout=self.delta_layout if delta else None))
+                start += nb
+        for p in out:
+            p.encode_s = sp.seconds / len(out)
         return out
 
     # -- decompress ----------------------------------------------------------
     def decompress(self, p: CompressedPayload):
-        if p.fused:
-            return self._decompress_fused(p)
-        return self._decompress_legacy(p)
+        with host_span("codec.decode", frames=1):
+            if p.fused:
+                return self._decompress_fused(p)
+            return self._decompress_legacy(p)
 
     def _fused_stream(self, p: CompressedPayload) -> np.ndarray:
         delta = p.mode == "int8_delta_zlib"
-        raw = p.blobs[0] if p.mode == "int8" else zlib.decompress(p.blobs[0])
+        raw = p.blobs[0] if p.mode == "int8" else _inflate(p.blobs[0])
         return np.frombuffer(raw, dtype=np.uint8 if delta else np.int8)
 
     def _decompress_fused(self, p: CompressedPayload):
@@ -456,7 +497,7 @@ class ActivationCodec:
                          for m in p.meta)
         leaves = _fused_decode_fn(segments, block, delta,
                                   p.delta_layout or "block")(
-            jnp.asarray(self._fused_stream(p)), jnp.asarray(p.scales[0]))
+            *_to_device(self._fused_stream(p), p.scales[0]))
         return jax.tree.unflatten(p.treedef, leaves)
 
     def decompress_group(self, ps: Sequence[CompressedPayload]) -> List[Any]:
@@ -478,11 +519,12 @@ class ActivationCodec:
                 segments.append((m.shape, m.dtype, m.n,
                                  start + m.block_start, m.delta_axis))
             start += sum(m.n_blocks for m in p.meta)
-        stream = np.concatenate([self._fused_stream(p) for p in ps])
-        scales = np.concatenate([p.scales[0] for p in ps])
-        leaves = _fused_decode_fn(tuple(segments), block, delta,
-                                  ps[0].delta_layout or "block")(
-            jnp.asarray(stream), jnp.asarray(scales))
+        with host_span("codec.decode", frames=len(ps)):
+            stream = np.concatenate([self._fused_stream(p) for p in ps])
+            scales = np.concatenate([p.scales[0] for p in ps])
+            leaves = _fused_decode_fn(tuple(segments), block, delta,
+                                      ps[0].delta_layout or "block")(
+                *_to_device(stream, scales))
         out, off = [], 0
         for p in ps:
             out.append(jax.tree.unflatten(p.treedef,

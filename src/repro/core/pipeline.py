@@ -32,7 +32,6 @@ run a GH200 or an NR uplink here -- DESIGN.md §2).
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -134,6 +133,14 @@ class HeadResult:
 
 @dataclass
 class EncodeResult:
+    """One payload through the codec.  With ``execute_model=True``,
+    ``quant_s`` is measured, not modelled: this payload's share of the
+    wall time of the codec's ``codec.encode`` span (core/telemetry.py),
+    device quant, device-to-host copy and zlib.  The engines add it to the
+    UE's compute time, so the simulated enqueue instant of the uplink
+    (``head_s + quant_s`` after the head starts) and everything after it
+    carry this host's wall time.  In accounting mode it is the fixed
+    10 ms the calibrated model charges."""
     quant_s: float
     raw_bytes: int
     compressed_bytes: int
@@ -190,14 +197,12 @@ def encode_stage(plan: SplitPlan, system: Calibrated, codec: ActivationCodec,
         raw, comp = system.payload_bytes(plan, SERVER_ONLY)
         return EncodeResult(0.0, raw, comp, payload)
     if execute_model:
-        t0 = time.perf_counter()
         comp = codec.compress(payload)
-        quant_s = time.perf_counter() - t0
         payload = codec.decompress(comp)             # server view
         if controller is not None:
             controller.observe_ratio(comp.compressed_bytes, comp.raw_bytes)
-        return EncodeResult(quant_s, comp.raw_bytes, comp.compressed_bytes,
-                            payload)
+        return EncodeResult(comp.encode_s, comp.raw_bytes,
+                            comp.compressed_bytes, payload)
     raw, comp = system.payload_bytes(plan, option, codec)
     return EncodeResult(0.010, raw, comp, payload)
 
@@ -226,14 +231,12 @@ def head_encode_stage(plan: SplitPlan, system: Calibrated,
                            execute_model, controller)
         return head, enc
     head_s = system.ue.compute_time_s(plan.head_flops(option))
-    t0 = time.perf_counter()
     comp, payload = codec.compress_head(producer, plan.params, img)
-    quant_s = time.perf_counter() - t0
     view = codec.decompress(comp)                    # server view
     if controller is not None:
         controller.observe_ratio(comp.compressed_bytes, comp.raw_bytes)
     return (HeadResult(head_s=head_s, payload=payload, local_out=None),
-            EncodeResult(quant_s, comp.raw_bytes, comp.compressed_bytes,
+            EncodeResult(comp.encode_s, comp.raw_bytes, comp.compressed_bytes,
                          view))
 
 
@@ -260,11 +263,10 @@ def encode_group_stage(plan: SplitPlan, system: Calibrated,
     if not execute_model or option in (UE_ONLY, SERVER_ONLY):
         return [encode_stage(plan, system, codec, p, option, execute_model, c)
                 for p, c in zip(payloads, controllers)]
-    # quant_s covers encode only, matching per-UE encode_stage (which stops
-    # its clock before the server-side decompress)
-    t0 = time.perf_counter()
+    # quant_s covers encode only, matching per-UE encode_stage (the
+    # server-side decompress lies outside every codec.encode span)
     comps = codec.compress_group(payloads)
-    quant_s = (time.perf_counter() - t0) / max(len(payloads), 1)
+    quant_s = sum(c.encode_s for c in comps) / max(len(payloads), 1)
     views = codec.decompress_group(comps)
     out = []
     for comp, view, ctrl in zip(comps, views, controllers):

@@ -50,6 +50,8 @@ from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
+from repro.core.telemetry import host_span
+
 # NR Table 5.1.3.1-1-flavoured spectral efficiencies (bits per resource
 # element) for MCS 0..27 -- used to *report* the MCS a grant's calibrated
 # efficiency corresponds to (KPM realism; the airlink stays continuous).
@@ -519,7 +521,15 @@ class RanStream:
     def advance(self, until_s: float,
                 harq_rng: np.random.Generator) -> List[StreamFlow]:
         """Run TTIs whose start is before ``until_s`` (pass ``inf`` to
-        drain).  Returns flows completed during this advance."""
+        drain).  Returns flows completed during this advance.  Runs in a
+        ``mac.advance`` host span that counts the TTIs it ran (``ttis``)."""
+        with host_span("mac.advance") as sp:
+            finished, steps = self._advance(until_s, harq_rng)
+            sp.set(ttis=steps)
+        return finished
+
+    def _advance(self, until_s: float, harq_rng: np.random.Generator
+                 ) -> Tuple[List[StreamFlow], int]:
         cfg = self.cfg
         finished: List[StreamFlow] = []
         steps = 0
@@ -591,7 +601,7 @@ class RanStream:
             self.cell.policy.observe(delivered, view)
             self._k += 1
             steps += 1
-        return finished
+        return finished, steps
 
     def _retire(self, cohort: int):
         """Drop a fully-drained cohort's flows: they no longer count in

@@ -35,6 +35,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.configs.swin_t_detection import SwinConfig
+from repro.core.telemetry import host_span
 from repro.models import swin as SW
 from repro.models import transformer as T
 
@@ -110,6 +111,22 @@ def stack_payloads(payloads: Sequence[Any], pad_to: Optional[int] = None):
     return stacked
 
 
+def upload_host_leaves(tree):
+    """``tree`` with every host (numpy) leaf put on the device in one
+    ``jax.device_put``, inside a ``copy.frame_h2d`` span; device leaves
+    pass through, and a tree without host leaves opens no span."""
+    leaves, treedef = jax.tree.flatten(tree)
+    host = [i for i, x in enumerate(leaves) if isinstance(x, np.ndarray)]
+    if not host:
+        return tree
+    with host_span("copy.frame_h2d",
+                   bytes=sum(leaves[i].nbytes for i in host)):
+        put = jax.device_put([leaves[i] for i in host])
+    for i, x in zip(host, put):
+        leaves[i] = x
+    return jax.tree.unflatten(treedef, leaves)
+
+
 def unstack_outputs(out, sizes: Sequence[int]) -> List[Any]:
     """Slice a batched tail output back into per-payload outputs."""
     outs, off = [], 0
@@ -137,23 +154,31 @@ class _PlanBase:
         Returns per-payload outputs in input order.  ``pad_to`` zero-pads
         the stacked batch (padding rows are dropped from the outputs); the
         jit cache is keyed per (option, executed batch) by tracing, so
-        callers should pad to a small set of bucket sizes.
+        callers should pad to a small set of bucket sizes.  The call is the
+        ``tail`` host span (frames, executed batch), with ``tail.stack``
+        (uploading any host leaf first), ``tail.dispatch`` and
+        ``tail.unstack`` under it; none waits for the device.
         """
         assert self.params is not None, "tail_batched needs real params"
         sizes = [payload_batch(p) for p in payloads]
         total = sum(sizes)
-        stacked = stack_payloads(payloads, pad_to=pad_to)
-        out = self._tail_jitted(option)(self.params, stacked)
-        if pad_to is not None and pad_to > total:
-            out = jax.tree.map(lambda a: a[:total], out)
-        return unstack_outputs(out, sizes)
+        with host_span("tail", frames=total, batch=max(pad_to or 0, total)):
+            with host_span("tail.stack"):
+                stacked = stack_payloads(upload_host_leaves(list(payloads)),
+                                         pad_to=pad_to)
+            with host_span("tail.dispatch"):
+                out = self._tail_jitted(option)(self.params, stacked)
+            with host_span("tail.unstack"):
+                if pad_to is not None and pad_to > total:
+                    out = jax.tree.map(lambda a: a[:total], out)
+                return unstack_outputs(out, sizes)
 
     def _tail_jitted(self, option: str):
         cache = self.__dict__.setdefault("_tail_jit_cache", {})
         if option not in cache:
-            cache[option] = jax.jit(
-                lambda params, payload, _o=option:
-                    self._tail_impl(params, payload, _o))
+            def plan_tail(params, payload):
+                return self._tail_impl(params, payload, option)
+            cache[option] = jax.jit(plan_tail)
         return cache[option]
 
 
@@ -179,11 +204,14 @@ class SwinSplitPlan(_PlanBase):
         """UE-side computation.  Returns (payload_tree_or_None, detections_or_None).
 
         Runs through the model-level trace caches (``head_apply_jit`` /
-        ``forward_full_jit``), so per-frame calls stop retracing."""
-        if option == UE_ONLY:
-            return None, SW.forward_full_jit(self.cfg)(self.params, img)
+        ``forward_full_jit``), so per-frame calls stop retracing.  A host
+        frame is uploaded first, in a ``copy.frame_h2d`` span; the
+        SERVER_ONLY frame leaves as it came (the edge's tail uploads it)."""
         if option == SERVER_ONLY:
             return {"img": img}, None
+        img = upload_host_leaves(img)
+        if option == UE_ONLY:
+            return None, SW.forward_full_jit(self.cfg)(self.params, img)
         return self.head_jitted(option)(self.params, img), None
 
     def head_jitted(self, option: str):
@@ -203,8 +231,11 @@ class SwinSplitPlan(_PlanBase):
         return SW.tail_apply(self.cfg, params, payload, l)
 
     def _tail_jitted(self, option: str):
-        if option not in (UE_ONLY, SERVER_ONLY):
-            # share the model-level trace cache across plan instances
+        # share the model-level trace caches across plan instances
+        if option == SERVER_ONLY:
+            full = SW.forward_full_jit(self.cfg)
+            return lambda params, payload: full(params, payload["img"])
+        if option != UE_ONLY:
             return SW.tail_apply_jit(self.cfg, int(option.removeprefix("split")))
         return super()._tail_jitted(option)
 

@@ -33,12 +33,24 @@ module only *collects* them:
 
 Export lives in ``core/trace_export.py`` (Chrome-trace/Perfetto JSON +
 flat JSONL).
+
+Everything above runs on the *simulated* clock.  The last section, host
+spans, is the one wall-clock plane: ``host_span`` marks a layer boundary
+of the served path (head, codec, MAC, tail, the host<->device copies) as
+a ``jax.profiler.TraceAnnotation``, visible beside the device ops under
+the profiler's host tracer, and, while a ``HostRecorder`` is attached
+with ``recording``, keeps it in memory on ``time.perf_counter``.
 """
 from __future__ import annotations
 
+import contextlib
+import threading
+import time
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import (Any, Dict, Iterator, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
+import jax
 import numpy as np
 
 # ---------------------------------------------------------------------------
@@ -473,3 +485,103 @@ class Telemetry:
                     end = b
             out[(log.ue_id, log.frame_idx)] = float(covered / (hi - lo))
         return out
+
+
+# ---------------------------------------------------------------------------
+# host spans (wall clock)
+# ---------------------------------------------------------------------------
+
+class HostSpanRecord(NamedTuple):
+    """One closed host span: ``time.perf_counter`` seconds, the name of
+    the span open around it on the same thread (None at the top), the
+    thread's ident, and the counts taken at its boundary."""
+    name: str
+    t0: float
+    t1: float
+    parent: Optional[str]
+    thread: int
+    attrs: Dict[str, Any]
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
+
+
+class HostRecorder:
+    """Keeps every host span closed while it is attached (``recording``).
+    Appends only; spans from any thread land in the one list."""
+
+    def __init__(self):
+        self.spans: List[HostSpanRecord] = []
+
+
+_RECORDER: Optional[HostRecorder] = None
+_ANNOTATION = jax.profiler.TraceAnnotation
+
+
+class _Stacks(threading.local):
+    """Per-thread stack of open span names: a span opened in a worker
+    thread starts its own parent chain."""
+
+    def __init__(self):
+        self.names: List[str] = []
+
+
+_STACKS = _Stacks()
+
+
+@contextlib.contextmanager
+def recording(rec: HostRecorder) -> Iterator[HostRecorder]:
+    """Attach ``rec`` for the body: every ``host_span`` that closes in
+    the meantime, on any thread, is appended to ``rec.spans``."""
+    global _RECORDER
+    prev, _RECORDER = _RECORDER, rec
+    try:
+        yield rec
+    finally:
+        _RECORDER = prev
+
+
+class HostSpan:
+    """Context manager returned by ``host_span``.  Its wall time
+    (``seconds``) is there after exit whether or not a recorder is
+    attached; ``set`` adds counts known only at the end (bytes out, TTIs
+    run).  It never waits for the device."""
+    __slots__ = ("name", "attrs", "t0", "t1", "parent", "_ann")
+
+    def __init__(self, name: str, attrs: Dict[str, Any]):
+        self.name, self.attrs = name, attrs
+        self.t0 = self.t1 = 0.0
+
+    def set(self, **attrs):
+        self.attrs.update(attrs)
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
+
+    def __enter__(self) -> "HostSpan":
+        self._ann = _ANNOTATION(self.name)
+        self._ann.__enter__()
+        stack = _STACKS.names
+        self.parent = stack[-1] if stack else None
+        stack.append(self.name)
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.t1 = time.perf_counter()
+        _STACKS.names.pop()
+        self._ann.__exit__(*exc)
+        rec = _RECORDER
+        if rec is not None:
+            rec.spans.append(HostSpanRecord(
+                self.name, self.t0, self.t1, self.parent,
+                threading.get_ident(), self.attrs))
+        return False
+
+
+def host_span(name: str, **attrs) -> HostSpan:
+    """A wall-clock span around one layer boundary of the served path,
+    with the counts taken there (bytes, TTIs, frames) as ``attrs``."""
+    return HostSpan(name, attrs)

@@ -78,6 +78,7 @@ from repro.core.pipeline import (EncodeResult, FrameLog, FrameSource,
                                  head_encode_stage, sense_stage)
 from repro.core.ran import MultiCell, RanStream, UplinkRequest
 from repro.core.splitting import UE_ONLY
+from repro.core.telemetry import host_span
 
 
 # ---------------------------------------------------------------------------
@@ -291,7 +292,18 @@ def run_stream(sim: CellSimulator, interference, imgs=None,
     lock-step engine: ``sim.frame_budget_s`` on a RAN cell, infinite on
     isolated links).  Resets seeded state first, exactly like
     ``CellSimulator.run``, so streaming-vs-lock-step comparisons are
-    rng-paired."""
+    rng-paired.  The call is the ``engine.run_stream`` host span, the
+    outermost of the served path (core/telemetry.py)."""
+    with host_span("engine.run_stream"):
+        return _run_stream(sim, interference, imgs, option, fps=fps,
+                           jitter_s=jitter_s, inflight=inflight,
+                           budget_s=budget_s, keep_outputs=keep_outputs)
+
+
+def _run_stream(sim: CellSimulator, interference, imgs,
+                option: Optional[str], *, fps, jitter_s,
+                inflight: Optional[int], budget_s: Optional[float],
+                keep_outputs: bool) -> CellResult:
     if option is not None and option not in sim._head_s:
         raise ValueError(f"unknown option {option!r}; "
                          f"plan offers {sim.plan.options}")
@@ -730,8 +742,9 @@ def run_stream(sim: CellSimulator, interference, imgs=None,
                 continue
             payload = local = None
             if sim.execute_model:
-                payload, local = sim.plan.head(src.frame(fr.idx, fr.ue),
-                                               fr.option)
+                with host_span("head", ue=fr.ue, frame=fr.idx):
+                    payload, local = sim.plan.head(src.frame(fr.idx, fr.ue),
+                                                   fr.option)
             fr.head = HeadResult(head_s=sim._head_s[fr.option],
                                  payload=payload, local_out=local)
         if fused:

@@ -336,13 +336,15 @@ _TAIL_JIT: Dict[Tuple[SwinConfig, int], Any] = {}
 
 
 def tail_apply_jit(cfg: SwinConfig, split: int):
-    """Cached jitted ``tail_apply`` for one (config, split).  The edge
-    server's batcher calls this once per micro-batch; padding occupancies
-    to bucketed batch sizes keeps the trace cache small."""
+    """Cached jitted ``tail_apply`` for one (config, split), the program
+    ``swin_tail``.  The edge server's batcher calls this once per
+    micro-batch; padding occupancies to bucketed batch sizes keeps the
+    trace cache small."""
     key = (cfg, split)
     if key not in _TAIL_JIT:
-        _TAIL_JIT[key] = jax.jit(
-            lambda params, boundary: tail_apply(cfg, params, boundary, split))
+        def swin_tail(params, boundary):
+            return tail_apply(cfg, params, boundary, split)
+        _TAIL_JIT[key] = jax.jit(swin_tail)
     return _TAIL_JIT[key]
 
 
@@ -352,14 +354,16 @@ _HEAD_JIT: Dict[Tuple[SwinConfig, int, bool], Any] = {}
 
 
 def head_apply_jit(cfg: SwinConfig, split: int, ship_merged: bool = True):
-    """Cached jitted ``head_apply`` for one (config, split, ship_merged).
-    The UE runs this once per frame; without the cache every frame paid a
-    full retrace (SwinConfig is frozen/hashable, so the key is cheap)."""
+    """Cached jitted ``head_apply`` for one (config, split, ship_merged),
+    the program ``swin_head``.  The UE runs this once per frame; without
+    the cache every frame paid a full retrace (SwinConfig is
+    frozen/hashable, so the key is cheap)."""
     key = (cfg, split, ship_merged)
     if key not in _HEAD_JIT:
-        _HEAD_JIT[key] = jax.jit(
-            lambda params, img: head_apply(cfg, params, img, split,
-                                           ship_merged=ship_merged))
+        def swin_head(params, img):
+            return head_apply(cfg, params, img, split,
+                              ship_merged=ship_merged)
+        _HEAD_JIT[key] = jax.jit(swin_head)
     return _HEAD_JIT[key]
 
 
@@ -367,10 +371,12 @@ _FULL_JIT: Dict[SwinConfig, Any] = {}
 
 
 def forward_full_jit(cfg: SwinConfig):
-    """Cached jitted whole-model forward (the UE_ONLY degenerate split)."""
+    """Cached jitted whole-model forward, the program ``swin_full``: the
+    UE_ONLY degenerate split, and the edge's program for SERVER_ONLY."""
     if cfg not in _FULL_JIT:
-        _FULL_JIT[cfg] = jax.jit(
-            lambda params, img: forward_full(cfg, params, img))
+        def swin_full(params, img):
+            return forward_full(cfg, params, img)
+        _FULL_JIT[cfg] = jax.jit(swin_full)
     return _FULL_JIT[cfg]
 
 
