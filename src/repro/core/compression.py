@@ -3,9 +3,13 @@
 Two stages, exactly as the paper:
   (1) FP32 -> INT8 per-block absmax quantization.  Device-side; runs the
       Pallas TPU kernels (bitwise-identical jnp path off-TPU, ops.py).
-  (2) zlib entropy coding of the int8 bytes.  Host-side: entropy coding is
-      inherently serial/byte-oriented, TPUs have no entropy-coder unit
-      (DESIGN.md §2) -- the paper likewise runs zlib on the UE CPU.
+  (2) zlib entropy coding of the int8 bytes.  Host-side: TPUs have no
+      entropy-coder unit (DESIGN.md §2) -- the paper likewise runs zlib on
+      the UE CPU.  Each payload's stream is cut into fixed ranges of
+      ``CHUNK_BLOCKS`` quant blocks, each its own zlib stream, so one
+      encode or decode pass deflates or inflates every chunk of every
+      payload at once on a shared host thread pool (zlib releases the
+      GIL).  Where the cuts fall depends on the payload's size alone.
 
 The codec operates on arbitrary pytrees (the Swin boundary payload is a
 dict of feature maps; LM split payloads carry the residual stream plus any
@@ -19,7 +23,7 @@ Two encoders produce interchangeable results:
     per grid step with ``delta_layout='block'``, or the legacy-equivalent
     per-leaf spatial delta fused into the same executable as an integer
     epilogue with the default ``'spatial'``); one device->host transfer
-    and one zlib call cover the whole payload.
+    and one deflate pass (its fixed-size chunks) covers the whole payload.
     Jitted encode/decode closures are trace-cached per (mode, quant
     block); jax.jit keys the per-leaf-shape-signature traces underneath,
     so nothing retraces per frame.  ``compress_group`` extends the same
@@ -40,7 +44,11 @@ encoder produced the payload (DESIGN.md §5).
 from __future__ import annotations
 
 import functools
+import os
+import threading
+import time
 import zlib
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -52,6 +60,11 @@ from repro.core.telemetry import host_span
 from repro.kernels import ops
 
 _INT8_MODES = ("int8", "int8_zlib", "int8_delta_zlib")
+
+# quant blocks per independent zlib stream of a fused payload: 256 KiB of
+# int8 at the configurations' quant_block of 8192.  Fixed, so the bytes on
+# the wire are the same on any host.
+CHUNK_BLOCKS = 32
 
 
 def spatial_delta_axis(shape: Tuple[int, ...]) -> Optional[int]:
@@ -91,16 +104,22 @@ class CompressedPayload:
     ``mode`` records the codec mode the payload was produced with, so the
     receiver decodes it correctly even if its own codec was constructed
     with a different default (None = legacy payload, decoder's mode wins).
-    ``encode_s`` is this payload's share of the wall time of the
-    ``codec.encode`` span that produced it (the span over the frames it
-    encoded); it is not part of the payload's value.
-    ``fused`` marks the single-stream layout: ``blobs``/``scales`` hold
-    ONE entry covering every leaf, and ``meta[i].block_start`` locates
-    leaf i's segment inside the stream.  ``delta_layout`` records which
+    ``encode_s`` is what one UE's own CPU spent on this payload: its
+    share of the ``codec.encode`` span's wall time outside ``codec.zlib``
+    (the span over the frames it encoded), plus the seconds its own
+    chunks took to deflate, on whatever threads ran them.  It is not part
+    of the payload's value.
+    ``fused`` marks the single-stream layout: ``scales`` holds ONE entry
+    covering every leaf, ``meta[i].block_start`` locates leaf i's segment
+    inside the stream, and ``blobs`` is the stream's consecutive ranges of
+    ``CHUNK_BLOCKS`` quant blocks (the last may be short), each an
+    independent zlib stream, in order: the stream is their inflated bytes
+    concatenated, so a payload of one blob decodes alike.  Mode 'int8'
+    ships the stream as one raw blob.  ``delta_layout`` records which
     delta geometry a fused delta stream was written with ('spatial' |
     'block'), so any receiver inverts it correctly."""
     blobs: List[bytes]                 # zlib(int8 blocks); one per tensor,
-                                       # or a single packed stream (fused)
+                                       # or the packed stream's chunks (fused)
     scales: List[np.ndarray]           # f32 per-block scales (shipped raw)
     meta: List[TensorMeta]
     raw_bytes: int                     # payload size before compression
@@ -281,11 +300,91 @@ def _to_device(stream: np.ndarray, scales: np.ndarray):
         return jax.device_put((stream, scales))
 
 
-def _inflate(blob: bytes) -> bytes:
-    with host_span("codec.zlib", bytes_in=len(blob)) as sp:
-        raw = zlib.decompress(blob)
-        sp.set(bytes_out=len(raw))
-    return raw
+# ---------------------------------------------------------------------------
+# host entropy stage: fixed-size zlib chunks on one thread pool
+# ---------------------------------------------------------------------------
+
+_POOL: Optional[Tuple[ThreadPoolExecutor, int]] = None
+_POOL_LOCK = threading.Lock()
+
+
+def _pool() -> Tuple[ThreadPoolExecutor, int]:
+    """The process's zlib pool and its width, made on first use: one
+    thread per usable CPU, at most 16."""
+    global _POOL
+    with _POOL_LOCK:
+        if _POOL is None:
+            width = min(len(os.sched_getaffinity(0)), 16)
+            _POOL = (ThreadPoolExecutor(width, thread_name_prefix="zlib"),
+                     width)
+        return _POOL
+
+
+def _pool_map(fn, jobs: Sequence[Any]) -> Tuple[List[Any], int]:
+    """``fn`` over ``jobs`` in order, and how many threads ran them: one
+    job runs inline, more go to the pool."""
+    if len(jobs) <= 1:
+        return [fn(j) for j in jobs], 1
+    pool, width = _pool()
+    return list(pool.map(fn, jobs)), min(width, len(jobs))
+
+
+def _chunks(stream: np.ndarray, block: int) -> List[memoryview]:
+    """A payload's int8 stream as views of its consecutive ranges of
+    ``CHUNK_BLOCKS`` quant blocks (the last may be short; an empty stream
+    is one empty range)."""
+    mv = memoryview(stream).cast("B")
+    step = CHUNK_BLOCKS * block
+    return [mv[i:i + step] for i in range(0, len(mv), step)] or [mv]
+
+
+def _deflate_chunk(job: Tuple[memoryview, int]) -> Tuple[bytes, float]:
+    buf, level = job
+    t0 = time.perf_counter()
+    # ``zlib`` looked up per call, so a wrap of the module's name sees it
+    blob = zlib.compress(buf, level)
+    return blob, time.perf_counter() - t0
+
+
+def _inflate_chunk(blob: bytes) -> Tuple[bytes, float]:
+    t0 = time.perf_counter()
+    raw = zlib.decompress(blob)
+    return raw, time.perf_counter() - t0
+
+
+def _zlib_pass(fn, jobs, per_payload: Sequence[int], bytes_in: int,
+               ) -> Tuple[List[List[Any]], List[float], float]:
+    """Run every chunk job of one pass on the pool under one
+    ``codec.zlib`` span.  ``per_payload`` counts each payload's jobs, in
+    order.  Returns each payload's outputs, each payload's summed chunk
+    seconds, and the span's wall seconds."""
+    with host_span("codec.zlib", payloads=len(per_payload),
+                   chunks=len(jobs), bytes_in=bytes_in) as sp:
+        done, threads = _pool_map(fn, jobs)
+        sp.set(threads=threads, bytes_out=sum(len(o) for o, _ in done))
+    outs, secs, i = [], [], 0
+    for n in per_payload:
+        part = done[i:i + n]
+        outs.append([o for o, _ in part])
+        secs.append(sum(t for _, t in part))
+        i += n
+    return outs, secs, sp.seconds
+
+
+def _inflate(ps: Sequence["CompressedPayload"]) -> np.ndarray:
+    """The fused payloads' streams, concatenated in order: every chunk of
+    every payload inflated in one pass (mode 'int8' blobs are the stream
+    as it is)."""
+    if ps[0].mode == "int8":
+        raws = [b for p in ps for b in p.blobs]
+    else:
+        blobs = [b for p in ps for b in p.blobs]
+        outs, _, _ = _zlib_pass(_inflate_chunk, blobs,
+                                [len(p.blobs) for p in ps],
+                                sum(map(len, blobs)))
+        raws = [r for out in outs for r in out]
+    delta = ps[0].mode == "int8_delta_zlib"
+    return np.frombuffer(b"".join(raws), dtype=np.uint8 if delta else np.int8)
 
 
 @dataclass
@@ -324,25 +423,39 @@ class ActivationCodec:
                              f"lane width); got {self.quant_block}")
         return self.fused and self.mode in _INT8_MODES
 
-    def _deflate(self, buf: bytes) -> bytes:
-        """zlib of one payload's int8 bytes (mode 'int8' ships them as
-        they are)."""
+    def _deflate(self, streams: Sequence[np.ndarray]
+                 ) -> Tuple[List[List[bytes]], List[float], float]:
+        """Each payload's int8 stream as its chunk blobs, every chunk of
+        the pass deflated in one pool pass (mode 'int8' ships a stream as
+        one blob, as it is).  Returns the blobs, each payload's summed
+        chunk seconds, and the pass's wall seconds."""
         if self.mode == "int8":
-            return buf
-        with host_span("codec.zlib", bytes_in=len(buf)) as sp:
-            blob = zlib.compress(buf, self.level)
-            sp.set(bytes_out=len(blob))
-        return blob
+            return [[s.tobytes()] for s in streams], [0.0] * len(streams), 0.0
+        cuts = [_chunks(s, self.quant_block) for s in streams]
+        return _zlib_pass(_deflate_chunk,
+                          [(c, self.level) for cs in cuts for c in cs],
+                          [len(cs) for cs in cuts],
+                          sum(s.nbytes for s in streams))
+
+    @staticmethod
+    def _set_encode_s(ps: Sequence[CompressedPayload], encode_s: float,
+                      zlib_s: float, chunk_s: Sequence[float]):
+        """Each payload's share of the pass outside zlib, plus its own
+        chunks' deflate seconds (``CompressedPayload.encode_s``)."""
+        for p, own in zip(ps, chunk_s):
+            p.encode_s = (encode_s - zlib_s) / len(ps) + own
 
     # -- compress -----------------------------------------------------------
     def compress(self, tree) -> CompressedPayload:
         with host_span("codec.encode", frames=1) as sp:
-            p = (self._compress_fused(tree) if self._use_fused()
-                 else self._compress_legacy(tree))
-        p.encode_s = sp.seconds
+            if self._use_fused():
+                p, zlib_s, chunk_s = self._compress_fused(tree)
+            else:
+                p, zlib_s, chunk_s = self._compress_legacy(tree), 0.0, 0.0
+        self._set_encode_s([p], sp.seconds, zlib_s, [chunk_s])
         return p
 
-    def _compress_fused(self, tree) -> CompressedPayload:
+    def _compress_fused(self, tree) -> Tuple[CompressedPayload, float, float]:
         leaves, treedef = jax.tree.flatten(tree)
         leaves = [jnp.asarray(x) for x in leaves]
         delta = self.mode == "int8_delta_zlib"
@@ -352,11 +465,11 @@ class ActivationCodec:
         metas, raw, _ = _segment_metas(
             leaves, self.quant_block,
             record_delta=delta and self.delta_layout == "spatial")
-        blob = self._deflate(stream.tobytes())
-        return CompressedPayload([blob], [scales], metas, raw, treedef,
-                                 mode=self.mode, fused=True,
-                                 delta_layout=self.delta_layout if delta
-                                 else None)
+        (blobs,), (chunk_s,), zlib_s = self._deflate([stream])
+        return (CompressedPayload(blobs, [scales], metas, raw, treedef,
+                                  mode=self.mode, fused=True,
+                                  delta_layout=self.delta_layout if delta
+                                  else None), zlib_s, chunk_s)
 
     def _compress_legacy(self, tree) -> CompressedPayload:
         leaves, treedef = jax.tree.flatten(tree)
@@ -431,19 +544,21 @@ class ActivationCodec:
             metas, raw, _ = _segment_metas(
                 leaves, self.quant_block,
                 record_delta=delta and self.delta_layout == "spatial")
-            blob = self._deflate(stream.tobytes())
-        return (CompressedPayload([blob], [scales], metas, raw, treedef,
-                                  mode=self.mode, fused=True,
-                                  delta_layout=self.delta_layout if delta
-                                  else None, encode_s=sp.seconds),
-                tree)
+            (blobs,), (chunk_s,), zlib_s = self._deflate([stream])
+        p = CompressedPayload(blobs, [scales], metas, raw, treedef,
+                              mode=self.mode, fused=True,
+                              delta_layout=self.delta_layout if delta
+                              else None)
+        self._set_encode_s([p], sp.seconds, zlib_s, [chunk_s])
+        return p, tree
 
     # -- batch-group compress (one launch across many payloads) -------------
     def compress_group(self, trees: Sequence[Any]) -> List[CompressedPayload]:
         """Encode many payloads in ONE device pass.
 
         The packed stream keeps every leaf's own quant blocks, and each
-        payload's byte range is zlib'd separately, so the returned
+        payload's byte range is cut into chunks from its own start, all
+        payloads' chunks deflated in one pool pass, so the returned
         payloads are byte-identical to per-payload ``compress`` -- the
         per-UE uplink accounting (and the receiver) can't tell the
         difference; only the encoder's wall clock can."""
@@ -461,21 +576,22 @@ class ActivationCodec:
             stream, scales = _fused_encode_fn(
                 self.quant_block, delta, self.delta_layout)(tuple(flat))
             stream, scales = _to_host(stream, scales)
-            out, start = [], 0
+            parts, start = [], 0
             for leaves, treedef in per_tree:
                 metas, raw, nb = _segment_metas(
                     leaves, self.quant_block,
                     record_delta=delta and self.delta_layout == "spatial")
-                blob = self._deflate(
-                    stream[start * self.quant_block:
-                           (start + nb) * self.quant_block].tobytes())
-                out.append(CompressedPayload(
-                    [blob], [scales[start:start + nb].copy()], metas, raw,
-                    treedef, mode=self.mode, fused=True,
-                    delta_layout=self.delta_layout if delta else None))
+                parts.append((metas, raw, treedef, start, start + nb))
                 start += nb
-        for p in out:
-            p.encode_s = sp.seconds / len(out)
+            blobs, chunk_s, zlib_s = self._deflate(
+                [stream[a * self.quant_block:b * self.quant_block]
+                 for *_, a, b in parts])
+            out = [CompressedPayload(
+                blob, [scales[a:b].copy()], metas, raw, treedef,
+                mode=self.mode, fused=True,
+                delta_layout=self.delta_layout if delta else None)
+                for blob, (metas, raw, treedef, a, b) in zip(blobs, parts)]
+        self._set_encode_s(out, sp.seconds, zlib_s, chunk_s)
         return out
 
     # -- decompress ----------------------------------------------------------
@@ -485,11 +601,6 @@ class ActivationCodec:
                 return self._decompress_fused(p)
             return self._decompress_legacy(p)
 
-    def _fused_stream(self, p: CompressedPayload) -> np.ndarray:
-        delta = p.mode == "int8_delta_zlib"
-        raw = p.blobs[0] if p.mode == "int8" else _inflate(p.blobs[0])
-        return np.frombuffer(raw, dtype=np.uint8 if delta else np.int8)
-
     def _decompress_fused(self, p: CompressedPayload):
         delta = p.mode == "int8_delta_zlib"
         block = p.meta[0].block if p.meta else self.quant_block
@@ -497,7 +608,7 @@ class ActivationCodec:
                          for m in p.meta)
         leaves = _fused_decode_fn(segments, block, delta,
                                   p.delta_layout or "block")(
-            *_to_device(self._fused_stream(p), p.scales[0]))
+            *_to_device(_inflate([p]), p.scales[0]))
         return jax.tree.unflatten(p.treedef, leaves)
 
     def decompress_group(self, ps: Sequence[CompressedPayload]) -> List[Any]:
@@ -520,7 +631,7 @@ class ActivationCodec:
                                  start + m.block_start, m.delta_axis))
             start += sum(m.n_blocks for m in p.meta)
         with host_span("codec.decode", frames=len(ps)):
-            stream = np.concatenate([self._fused_stream(p) for p in ps])
+            stream = _inflate(ps)
             scales = np.concatenate([p.scales[0] for p in ps])
             leaves = _fused_decode_fn(tuple(segments), block, delta,
                                       ps[0].delta_layout or "block")(

@@ -134,9 +134,11 @@ class HeadResult:
 @dataclass
 class EncodeResult:
     """One payload through the codec.  With ``execute_model=True``,
-    ``quant_s`` is measured, not modelled: this payload's share of the
-    wall time of the codec's ``codec.encode`` span (core/telemetry.py),
-    device quant, device-to-host copy and zlib.  The engines add it to the
+    ``quant_s`` is measured, not modelled: the payload's
+    ``CompressedPayload.encode_s``, its share of the codec's
+    ``codec.encode`` span outside zlib (device quant, device-to-host copy;
+    core/telemetry.py) plus its own zlib chunks' seconds, what one UE's
+    own CPU would spend.  The engines add it to the
     UE's compute time, so the simulated enqueue instant of the uplink
     (``head_s + quant_s`` after the head starts) and everything after it
     carry this host's wall time.  In accounting mode it is the fixed
@@ -252,12 +254,14 @@ def encode_group_stage(plan: SplitPlan, system: Calibrated,
     byte-identical to per-UE ``compress`` (the uplink accounting and the
     receiver see exactly the per-UE path), then ``decompress_group``
     rebuilds all server views with one launch, device-resident for
-    ``tail_batched``.  Per-UE ``quant_s`` is the group's encode wall time
-    divided by the group size: encode cost is ~linear in payload bytes
-    (kernel + per-UE zlib slice), so total/B estimates the time ONE UE's
-    own device would spend on its own payload -- the quantity the energy
-    and delay models charge.  (The same holds for the serial fallback,
-    where total/B is exactly the mean per-payload time.)  Falls back to
+    ``tail_batched``.  Per-UE ``quant_s`` is the mean of the payloads'
+    ``encode_s``: the group's encode wall time outside the zlib pass over
+    B, plus the summed seconds of every payload's zlib chunks over B.  The
+    chunks run in parallel on this host's pool, but each is timed where it
+    ran, so the mean estimates the time ONE UE's own CPU would spend on
+    its own payload -- the quantity the energy and delay models charge --
+    however many cores the simulating host has.  (For the serial
+    fallback it is the mean per-payload time.)  Falls back to
     per-payload ``encode_stage`` for the degenerate options and
     accounting-only mode."""
     if not execute_model or option in (UE_ONLY, SERVER_ONLY):

@@ -486,7 +486,7 @@ def test_cell_group_encode_bit_identical_to_per_ue(swin_exec):
         group = codec.compress_group(payloads)
         solo = [codec.compress(p) for p in payloads]
         for g, s in zip(group, solo):
-            assert g.blobs[0] == s.blobs[0]
+            assert g.blobs == s.blobs
             np.testing.assert_array_equal(g.scales[0], s.scales[0])
             assert g.compressed_bytes == s.compressed_bytes
         views = codec.decompress_group(group)

@@ -138,7 +138,7 @@ def test_compress_group_bit_identical_to_per_tree():
         group = codec.compress_group(trees)
         solo = [codec.compress(t) for t in trees]
         for g, s in zip(group, solo):
-            assert g.blobs[0] == s.blobs[0]
+            assert g.blobs == s.blobs
             np.testing.assert_array_equal(g.scales[0], s.scales[0])
             assert g.compressed_bytes == s.compressed_bytes
             assert [m.block_start for m in g.meta] == \

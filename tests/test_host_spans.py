@@ -12,6 +12,7 @@ import pytest
 
 from repro.configs.swin_t_detection import reduced
 from repro.core import calibration as C
+from repro.core import compression as CC
 from repro.core import telemetry as T
 from repro.core.cell import CellSimulator
 from repro.core.compression import ActivationCodec
@@ -91,7 +92,17 @@ def _by_name(spans):
     return out
 
 
-def test_split2_records_every_span_once_per_occurrence(split2):
+def _blocks_per_payload(swin):
+    plan = SwinSplitPlan(swin[0], None)
+    return sum(math.ceil(math.prod(shape) / ActivationCodec().quant_block)
+               for shape, _ in plan.payload_specs("split2"))
+
+
+def _chunks_per_payload(swin):
+    return math.ceil(_blocks_per_payload(swin) / CC.CHUNK_BLOCKS)
+
+
+def test_split2_records_every_span_once_per_occurrence(swin, split2):
     _, spans, _ = split2
     got = Counter((s.name, s.parent) for s in spans)
     assert got == Counter({
@@ -101,9 +112,9 @@ def test_split2_records_every_span_once_per_occurrence(split2):
         ("codec.encode", "engine.run_stream"): 1,      # one group encode
         ("codec.wait", "codec.encode"): 1,
         ("copy.codec_d2h", "codec.encode"): 1,
-        ("codec.zlib", "codec.encode"): N_UES,         # one per payload
+        ("codec.zlib", "codec.encode"): 1,             # one pass for both
         ("codec.decode", "engine.run_stream"): 1,
-        ("codec.zlib", "codec.decode"): N_UES,
+        ("codec.zlib", "codec.decode"): 1,
         ("copy.codec_h2d", "codec.decode"): 1,
         ("mac.advance", "engine.run_stream"):
             got[("mac.advance", "engine.run_stream")],
@@ -121,6 +132,9 @@ def test_split2_records_every_span_once_per_occurrence(split2):
     assert by["tail"][0].attrs == {"frames": N_UES, "batch": N_UES}
     for s in by["codec.zlib"]:
         assert s.attrs["bytes_in"] > 0 and s.attrs["bytes_out"] > 0
+        assert s.attrs["payloads"] == N_UES
+        assert s.attrs["chunks"] == N_UES * _chunks_per_payload(swin)
+        assert s.attrs["threads"] == min(CC._pool()[1], s.attrs["chunks"])
     for s in spans:
         assert s.t0 <= s.t1 and s.thread == threading.get_ident()
     outer = by["engine.run_stream"][0]
@@ -135,16 +149,18 @@ def test_copy_bytes_are_what_crosses(swin, split2):
         [im.nbytes for im in imgs]
     # the int8 stream and the f32 scales of both payloads, in one download
     block = ActivationCodec().quant_block
-    plan = SwinSplitPlan(cfg, None)
-    n_blocks = N_UES * sum(math.ceil(math.prod(shape) / block)
-                           for shape, _ in plan.payload_specs("split2"))
+    n_blocks = N_UES * _blocks_per_payload(swin)
     assert by["copy.codec_d2h"][0].attrs["bytes"] == n_blocks * (block + 4)
     assert by["copy.codec_h2d"][0].attrs["bytes"] == n_blocks * (block + 4)
     # what zlib wrote, plus the scales, is what each UE sent
-    enc = [s for s in by["codec.zlib"] if s.parent == "codec.encode"]
+    (enc,) = [s for s in by["codec.zlib"] if s.parent == "codec.encode"]
+    (dec,) = [s for s in by["codec.zlib"] if s.parent == "codec.decode"]
     sent = sum(l.compressed_bytes for l in res.logs)
-    assert sum(s.attrs["bytes_out"] for s in enc) + 4 * n_blocks == sent
-    assert sum(s.attrs["bytes_in"] for s in enc) == n_blocks * block
+    assert enc.attrs["bytes_out"] + 4 * n_blocks == sent
+    assert enc.attrs["bytes_in"] == n_blocks * block
+    # decode inflates exactly what encode deflated
+    assert (dec.attrs["bytes_in"], dec.attrs["bytes_out"]) == \
+        (enc.attrs["bytes_out"], enc.attrs["bytes_in"])
 
 
 def test_ttis_are_the_mac_s_own_steps(split2):
@@ -154,10 +170,19 @@ def test_ttis_are_the_mac_s_own_steps(split2):
 
 
 def test_quant_s_is_the_share_of_the_encode_span(split2):
+    """Each UE is charged its share of the encode pass outside zlib plus
+    the seconds its own chunks took to deflate; the engine gives every UE
+    of the group the mean.  So the UEs' summed deflate seconds are what
+    their ``quant_s`` holds beyond the non-zlib share: more than nothing,
+    and no more than the pass's threads could run in its wall time."""
     res, spans, _ = split2
-    enc = _by_name(spans)["codec.encode"][0]
-    for log in res.logs:
-        assert log.quant_s == pytest.approx(enc.seconds / N_UES, rel=1e-12)
+    by = _by_name(spans)
+    enc = by["codec.encode"][0]
+    (zl,) = [s for s in by["codec.zlib"] if s.parent == "codec.encode"]
+    quant_s = {log.quant_s for log in res.logs}
+    assert len(res.logs) == N_UES and len(quant_s) == 1
+    deflate_s = N_UES * quant_s.pop() - (enc.seconds - zl.seconds)
+    assert 0.0 < deflate_s <= zl.seconds * zl.attrs["threads"]
 
 
 def test_server_only_uploads_the_frame_in_the_tail_stack(swin, server_only):
