@@ -1,10 +1,10 @@
-"""Chip smoke run: the published Swin-T split path on one TPU.
+"""Chip smoke run: a published Swin split path on one TPU.
 
-    PYTHONPATH=src python chip_smoke.py
+    PYTHONPATH=src python chip_smoke.py [swin_t|swin_s|swin_b]
 
 Drives the served path once, in this one process, through the entry points
-a user calls, at the published width (Swin-T: 96 wide, depths 2-2-6-2,
-800x544 input; random weights from seed 0):
+a user calls, at the published widths of the named preset (default Swin-T:
+96 wide, depths 2-2-6-2; 800x544 input; random weights from seed 0):
 
   1. ``build_pipeline`` + ``SplitInferencePipeline.run_trace`` with each of
      the six split options forced once, then a few adaptive frames;
@@ -17,7 +17,9 @@ It checks that the Pallas kernels ran (``ops.on_tpu()``, and
 split); that detections agree with a plain f32 reference (``attn_impl=
 'xla'``, no codec, matmul precision 'highest') within ``REL_L2_TOL``; that
 the fused window kernel agrees with its jnp mirror at every stage within
-``KERNEL_TOL``; and that raw payload bytes equal the tracked table.
+``KERNEL_TOL``; and that raw payload bytes equal the tracked table (Swin-T,
+the only preset it covers) or, for the other presets, the plan's shape
+accounting.
 
 Lines before the last are smoke readings, not benchmark results.  The last
 line is the JSON status.  With no TPU, or when any check fails, it exits
@@ -38,13 +40,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.swin_t_detection import CONFIG
+from repro.configs.swin_t_detection import PUBLISHED, SWIN_T
 from repro.core.calibration import load_payload_table
 from repro.core.cell import CellSimulator, cell_interference_traces
 from repro.core.compression import ActivationCodec
 from repro.core.pipeline import build_pipeline
 from repro.core.ran import RanCell, RanConfig, make_policy
-from repro.core.splitting import SERVER_ONLY, UE_ONLY
+from repro.core.splitting import SERVER_ONLY, UE_ONLY, SwinSplitPlan
 from repro.data.video import SyntheticVideo, VideoConfig
 from repro.kernels import ops
 from repro.kernels import window_attention as wa
@@ -193,9 +195,9 @@ def phase_codec_kernels(codec, payload):
         f"kernels over a {flat.shape[0] * 4} byte stream")
 
 
-def phase_splits(cfg, params, img, table):
+def phase_splits(cfg, params, img, raw):
     """Per split: tpu_custom_call in the compiled head and tail, detections
-    against the reference, payload bytes against the table, warm head and
+    against the reference, raw payload bytes against ``raw``, warm head and
     tail wall time.  Returns compile seconds (first call minus warm call
     of every program run here)."""
     codec = ActivationCodec()
@@ -235,12 +237,9 @@ def phase_splits(cfg, params, img, table):
         err = rel_l2(out, ref)
         say(f"{opt} rel_l2", f"{err:.3e} tol={REL_L2_TOL:.1e}")
         require(err <= REL_L2_TOL, f"{opt}: rel L2 {err} > {REL_L2_TOL}")
-        row = table[opt]
         say(f"{opt} payload_bytes",
-            f"raw={comp.raw_bytes} (table {row['raw']}) "
-            f"compressed={comp.compressed_bytes} "
-            f"(table {row['compressed']})")
-        require(comp.raw_bytes == row["raw"], f"{opt} raw bytes differ")
+            f"raw={comp.raw_bytes} compressed={comp.compressed_bytes}")
+        require(comp.raw_bytes == raw[opt], f"{opt} raw bytes differ")
         head_s = timed(lambda: head(params, img))
         tail_s = timed(lambda: tail(params, view))
         say(f"{opt} wall_s", f"head={head_s:.6f} tail={tail_s:.6f} "
@@ -249,7 +248,7 @@ def phase_splits(cfg, params, img, table):
     return compile_s
 
 
-def phase_pipeline(cfg, imgs, table):
+def phase_pipeline(cfg, imgs, raw):
     """run_trace with each option forced (first call, then warm), then
     adaptive frames.  Returns (pipeline, compile seconds: first call minus
     warm call, summed -- what the head/tail jits above did not cover, such
@@ -269,7 +268,7 @@ def phase_pipeline(cfg, imgs, table):
         compile_s += cold - warm
         require(np.isfinite(log.delay_s) and log.option == opt,
                 f"run_trace {opt}: {log}")
-        require(log.raw_bytes == table[opt]["raw"],
+        require(log.raw_bytes == raw[opt],
                 f"run_trace {opt}: raw bytes {log.raw_bytes}")
         say(f"run_trace {opt}", f"first_call_s={cold:.3f} "
             f"warm_call_s={warm:.3f} compressed={log.compressed_bytes}")
@@ -317,7 +316,8 @@ def phase_cell(pipe, imgs, ref):
             "fused-head and group-encode payload bytes differ")
 
 
-def main() -> int:
+def main(argv) -> int:
+    cfg = PUBLISHED[argv[0] if argv else "swin_t"]
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         print(f"chip_smoke: needs a TPU; JAX found {dev.platform!r}",
@@ -327,17 +327,19 @@ def main() -> int:
     require(ops.on_tpu(), "ops.on_tpu() is false on a TPU backend")
     say("device_kind", dev.device_kind)
     say("compile_cache", cache_dir)
-    cfg = CONFIG
-    require((cfg.embed_dim, cfg.depths, cfg.img_w, cfg.img_h)
-            == (96, (2, 2, 6, 2), 800, 544), "not the published Swin-T")
+    say("config", cfg.name)
     t_start = time.perf_counter()
     params = SW.init(cfg, jax.random.PRNGKey(SEED))
     imgs = frames(cfg, len(ADAPTIVE_LEVELS))
-    table = load_payload_table()
+    if cfg is SWIN_T:
+        raw = {opt: row["raw"] for opt, row in load_payload_table().items()}
+    else:
+        plan = SwinSplitPlan(cfg, None)
+        raw = {opt: plan.raw_payload_bytes(opt) for opt in plan.options}
 
     phase_kernel_vs_mirror(cfg)
-    compile_s = phase_splits(cfg, params, imgs[0], table)
-    pipe, pipe_compile_s = phase_pipeline(cfg, imgs, table)
+    compile_s = phase_splits(cfg, params, imgs[0], raw)
+    pipe, pipe_compile_s = phase_pipeline(cfg, imgs, raw)
     say("compile_s", f"{compile_s + pipe_compile_s:.3f} (first call minus "
         f"warm call: head/codec/tail/reference jits {compile_s:.3f}, "
         f"run_trace {pipe_compile_s:.3f})")
@@ -351,4 +353,4 @@ def main() -> int:
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    sys.exit(main(sys.argv[1:]))

@@ -1,8 +1,10 @@
-"""Swin-T object-detection backbone -- the paper's own model (Fig. 2).
+"""Swin object-detection backbones -- the paper's own model (Fig. 2).
 
 Swin-T (arXiv:2103.14030): depths (2,2,6,2), dims (96,192,384,768), heads
 (3,6,12,24), window 7, patch 4.  Detection input defaults to 800x544 RGB
 uint8 = 1.306 MB, matching the paper's stated 1.312 MB input payload.
+Swin-S and Swin-B, from the same paper, are published presets beside it
+(``PUBLISHED``, by name); ``reduced()`` is the tests' small model.
 
 The four stage boundaries are the paper's split points S1..S4.  The detection
 head (FPN + dense head) always runs on the server side; we implement a
@@ -50,7 +52,16 @@ class SwinConfig:
         return h, w
 
 
-CONFIG = SwinConfig()
+# The published backbones (arXiv:2103.14030 §3.3), each at the paper's
+# 800x544 detection input with the FPN + FCOS-style head.
+SWIN_T = SwinConfig()   # C 96, depths 2-2-6-2, heads 3-6-12-24, window 7
+SWIN_S = SwinConfig(    # C 96, depths 2-2-18-2, heads 3-6-12-24, window 7
+    name="swin-s-detection", depths=(2, 2, 18, 2))
+SWIN_B = SwinConfig(    # C 128, depths 2-2-18-2, heads 4-8-16-32, window 7
+    name="swin-b-detection", embed_dim=128, depths=(2, 2, 18, 2),
+    num_heads=(4, 8, 16, 32))
+PUBLISHED = {"swin_t": SWIN_T, "swin_s": SWIN_S, "swin_b": SWIN_B}
+CONFIG = SWIN_T
 
 
 def reduced() -> SwinConfig:

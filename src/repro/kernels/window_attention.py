@@ -167,6 +167,56 @@ def _fused_kernel_shift(a_ref, b_ref, bias_ref, mask_ref, o_ref, carry_ref, *,
     carry_ref[...] = cur[window - shift:]
 
 
+DEFAULT_SCOPED_VMEM = 16 * 2 ** 20   # the TPU compiler's scoped-VMEM limit
+VMEM_BYTES = 128 * 2 ** 20           # all of one v5e TensorCore's VMEM
+
+
+def band_vmem_bytes(rows: int, Wp: int, C: int, n_heads: int, W2P: int,
+                    shift: int, dtype) -> int:
+    """VMEM one grid step of the fused band kernel holds, from its block
+    shapes: a band of ``rows`` (the window) image rows, ``Wp`` wide, with
+    ``C`` channels in ``n_heads`` heads, windows padded to ``W2P`` tokens.
+    Every array is counted in VMEM's (8, 128) tiles: its last dimension
+    padded to 128 lanes, the one before to 8 sublanes.
+
+    Double-buffered by the pipeline: the qkv band (two when ``shift``
+    rolls the map: the band and the next), the output band, the bias table
+    and the band's mask.  Single: the ``shift``-row carry and the f32
+    working set -- the assembled band, its partitioned padded windows,
+    one head's logits and probabilities, the windows' output and the
+    un-partitioned band.  An upper estimate of what the TPU compiler
+    (v5e) counts: Swin-B's shifted stage-2 band 19.73 MiB against its
+    16.28 MiB; Swin-T's shifted stage-0 band 19.18 MiB against 17.27-17.40
+    MiB inside the batched whole-model program.
+    """
+    def tiles(*shape):
+        *lead, a, b = shape
+        n = -(-a // 8) * 8 * (-(-b // 128) * 128)
+        for d in lead:
+            n *= d
+        return n
+
+    it = jnp.dtype(dtype).itemsize
+    nww = Wp // rows
+    f32 = 4
+    pipelined = (((2 if shift else 1) * tiles(rows, Wp, 3 * C)
+                  + tiles(rows, Wp, C)) * it
+                 + tiles(n_heads, W2P, W2P) * f32 + tiles(nww, W2P, W2P))
+    working = f32 * (tiles(rows, Wp, 3 * C) + tiles(nww, W2P, 3 * C)
+                     + 2 * tiles(nww, W2P, W2P) + tiles(nww, W2P, C)
+                     + tiles(rows, Wp, C))
+    return 2 * pipelined + tiles(shift, Wp, C) * f32 + working
+
+
+def band_vmem_limit(rows: int, Wp: int, C: int, n_heads: int, W2P: int,
+                    shift: int, dtype):
+    """The scoped-VMEM limit the kernel asks for: None (the compiler's
+    default) where ``band_vmem_bytes`` fits under it, else that estimate,
+    never more than a v5e core holds."""
+    need = band_vmem_bytes(rows, Wp, C, n_heads, W2P, shift, dtype)
+    return None if need <= DEFAULT_SCOPED_VMEM else min(need, VMEM_BYTES)
+
+
 def fused_window_attention_pallas(qkv, bias, mask, *, window: int, shift: int,
                                   n_heads: int, interpret: bool):
     """One-launch Swin window attention over a whole feature map.
@@ -180,11 +230,13 @@ def fused_window_attention_pallas(qkv, bias, mask, *, window: int, shift: int,
     shift == 0 is a direct grid (B, nwh): one step = one band in, one band
     out.  shift > 0 runs (B, nwh + 1) steps with the carry scheme above
     (band nwh-1 is visited twice; the extra step is the pipeline drain).
-    VMEM per step: two input bands + one output band + the (shift, Wp, C)
-    carry.  At the published Swin-T's stage 0 (Wp=203, 3C=288) the TPU
-    compiler accepts the kernel down to a scoped-VMEM limit of 6.7 MiB
-    unshifted and 7.9 MiB shifted (v5e, compiled without a chip), under
-    the 16 MiB default.
+    VMEM per step: ``band_vmem_bytes``.  Where that lies above the
+    compiler's scoped-VMEM default the call raises its limit to it
+    (``band_vmem_limit``): the shifted bands of Swin-T's and Swin-S's
+    stages 0, 1 and 3, Swin-B's shifted bands and its unshifted stage 3.
+    Without it Swin-B's 512-wide shifted stage-2 band and, inside a
+    whole-model program at batch > 1, the shifted stage-0 band are
+    refused on a v5e.
     """
     B, Hp, Wp, C3 = qkv.shape
     C = C3 // 3
@@ -193,6 +245,9 @@ def fused_window_attention_pallas(qkv, bias, mask, *, window: int, shift: int,
     W2P = mask.shape[-1]
     sm_scale = 1.0 / math.sqrt(C // n_heads)
     out_shape = jax.ShapeDtypeStruct((B, Hp, Wp, C), qkv.dtype)
+    limit = band_vmem_limit(window, Wp, C, n_heads, W2P, shift, qkv.dtype)
+    vmem = ({} if limit is None else
+            {"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=limit)})
     bias_spec = pl.BlockSpec(bias.shape, lambda b, t: (0, 0, 0))
     mask_block = (1,) + mask.shape[1:]
 
@@ -212,6 +267,7 @@ def fused_window_attention_pallas(qkv, bias, mask, *, window: int, shift: int,
                                    lambda b, t: (b, t, 0, 0)),
             out_shape=out_shape,
             interpret=interpret,
+            **vmem,
         )(qkv, bias, mask)
 
     kernel = functools.partial(
@@ -236,6 +292,7 @@ def fused_window_attention_pallas(qkv, bias, mask, *, window: int, shift: int,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((shift, Wp, C), jnp.float32)],
         interpret=interpret,
+        **vmem,
     )(qkv, qkv, bias, mask)
 
 

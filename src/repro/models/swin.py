@@ -262,13 +262,16 @@ def patch_merge(cfg: SwinConfig, p, x):
 
 def stage_apply(cfg: SwinConfig, params, x, stage: int):
     """Run stage ``stage`` (blocks + trailing merge).  Returns
-    (pre_merge_feature, post_merge_x)."""
+    (pre_merge_feature, post_merge_x).  The blocks run under the name
+    scope ``swin.stage<stage>``, which names their un-fused operations
+    (the window kernel's launches among them) in a device trace."""
     sp = params["stages"][stage]
     H, W = x.shape[1], x.shape[2]
     nh = cfg.num_heads[stage]
-    for bi, bp in enumerate(sp["blocks"]):
-        shift = 0 if bi % 2 == 0 else cfg.window // 2
-        x = swin_block(cfg, bp, x, H, W, nh, shift)
+    with jax.named_scope(f"swin.stage{stage}"):
+        for bi, bp in enumerate(sp["blocks"]):
+            shift = 0 if bi % 2 == 0 else cfg.window // 2
+            x = swin_block(cfg, bp, x, H, W, nh, shift)
     feat = x
     if "merge" in sp:
         x = patch_merge(cfg, sp["merge"], x)

@@ -6,7 +6,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs.swin_t_detection import CONFIG as FULL, reduced
+from repro.configs.swin_t_detection import (CONFIG as FULL, PUBLISHED,
+                                            SWIN_T, reduced)
 from repro.models import swin as SW
 
 
@@ -37,6 +38,23 @@ def test_stage_hw_and_dims():
     assert FULL.stage_hw(0) == (136, 200)
     assert FULL.stage_hw(3) == (17, 25)
     assert FULL.stage_dim(3) == 768
+
+
+@pytest.mark.parametrize("name,dims,depths,heads,gflop,mparams", [
+    ("swin_t", 96, (2, 2, 6, 2), (3, 6, 12, 24), 210.1, 31.4),
+    ("swin_s", 96, (2, 2, 18, 2), (3, 6, 12, 24), 284.0, 52.8),
+    ("swin_b", 128, (2, 2, 18, 2), (4, 8, 16, 32), 401.1, 90.8),
+])
+def test_published_presets(name, dims, depths, heads, gflop, mparams):
+    """arXiv:2103.14030 §3.3 at the paper's 800x544 detection input."""
+    cfg = PUBLISHED[name]
+    assert (cfg.embed_dim, cfg.depths, cfg.num_heads, cfg.window,
+            cfg.img_w, cfg.img_h) == (dims, depths, heads, 7, 800, 544)
+    assert SW.total_flops(cfg) / 1e9 == pytest.approx(gflop, abs=0.05)
+    shapes = jax.eval_shape(lambda: SW.init(cfg, jax.random.PRNGKey(0)))
+    n = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(shapes))
+    assert n / 1e6 == pytest.approx(mparams, abs=0.05)
+    assert PUBLISHED["swin_t"] is SWIN_T is FULL
 
 
 def test_flops_total_is_sum_of_parts():

@@ -20,7 +20,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.configs.swin_t_detection import CONFIG
+from repro.configs.swin_t_detection import CONFIG, SWIN_B
 from repro.kernels import codec as ck
 from repro.kernels import quant as qk
 from repro.kernels import window_attention as wa
@@ -55,25 +55,64 @@ def _compile(fn, *shapes):
     return compiled
 
 
-def _stage_geometry(stage: int):
+def _stage_geometry(stage: int, cfg=CONFIG):
     """(Hp, Wp, C, heads) of stage ``stage``'s window-padded feature map."""
-    h, w = CONFIG.stage_hw(stage)
-    win = CONFIG.window
-    return (-(-h // win) * win, -(-w // win) * win, CONFIG.stage_dim(stage),
-            CONFIG.num_heads[stage])
+    h, w = cfg.stage_hw(stage)
+    win = cfg.window
+    return (-(-h // win) * win, -(-w // win) * win, cfg.stage_dim(stage),
+            cfg.num_heads[stage])
 
 
 def test_stage_geometries_are_the_published_ones():
     assert [_stage_geometry(s) for s in range(4)] == [
         (140, 203, 96, 3), (70, 105, 192, 6), (35, 56, 384, 12),
         (21, 28, 768, 24)]
+    assert [_stage_geometry(s, SWIN_B) for s in range(4)] == [
+        (140, 203, 128, 4), (70, 105, 256, 8), (35, 56, 512, 16),
+        (21, 28, 1024, 32)]
+
+
+def _band_limit(stage: int, shift: int, cfg=CONFIG):
+    _, Wp, C, nh = _stage_geometry(stage, cfg)
+    return wa.band_vmem_limit(cfg.window, Wp, C, nh, 64, shift, jnp.float32)
+
+
+def test_band_vmem_limit_from_the_block_shapes():
+    """The limit the kernel asks for: the compiler's default wherever the
+    band's estimate fits under it, else the estimate, which lies above
+    the compiler's own count at the two bands that were refused without
+    it (Swin-B's shifted stage 2: 16.28 MiB; Swin-T's shifted stage 0 in
+    the batched whole-model program: 17.40 MiB)."""
+    mib = 2 ** 20
+    assert [_band_limit(s, 0) for s in range(4)] == [None] * 4
+    assert _band_limit(2, 3) is None
+    assert _band_limit(2, 3, SWIN_B) > 16.28 * mib
+    assert _band_limit(0, 3) > 17.40 * mib
+    for stage in range(4):
+        for shift in (0, 3):
+            for cfg in (CONFIG, SWIN_B):
+                _, Wp, C, nh = _stage_geometry(stage, cfg)
+                need = wa.band_vmem_bytes(7, Wp, C, nh, 64, shift,
+                                          jnp.float32)
+                limit = _band_limit(stage, shift, cfg)
+                if need <= wa.DEFAULT_SCOPED_VMEM:
+                    assert limit is None
+                else:
+                    assert limit == min(need, wa.VMEM_BYTES)
+    assert wa.band_vmem_limit(7, 4096, 4096, 32, 64, 3, jnp.float32) == \
+        wa.VMEM_BYTES
+
+
+# Swin-T's cases keep the ids they had before Swin-B's joined them.
+STAGES = ([pytest.param(CONFIG, s, id=str(s)) for s in range(4)]
+          + [pytest.param(SWIN_B, s, id=f"swin_b-{s}") for s in range(4)])
 
 
 @pytest.mark.parametrize("shift", [0, 3])
-@pytest.mark.parametrize("stage", [0, 1, 2, 3])
-def test_fused_window_attention_compiles(one_chip, stage, shift):
-    Hp, Wp, C, nh = _stage_geometry(stage)
-    win = CONFIG.window
+@pytest.mark.parametrize("cfg,stage", STAGES)
+def test_fused_window_attention_compiles(one_chip, cfg, stage, shift):
+    Hp, Wp, C, nh = _stage_geometry(stage, cfg)
+    win = cfg.window
     W2P = 64
     assert shift in (0, win // 2)
 
@@ -89,6 +128,24 @@ def test_fused_window_attention_compiles(one_chip, stage, shift):
                                   sharding=one_chip),
              jax.ShapeDtypeStruct((Hp // win, Wp // win, W2P, W2P), jnp.int8,
                                   sharding=one_chip))
+
+
+def test_batched_whole_model_compiles(one_chip, monkeypatch):
+    """``swin_full`` at batch 2, the edge's batched ``server_only``
+    program, with the Pallas kernels it runs on a TPU: its shifted
+    stage-0 band is refused at the compiler's default VMEM limit."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    params = jax.eval_shape(lambda: SW.init(CONFIG, jax.random.PRNGKey(0)))
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), params)
+    img = jax.ShapeDtypeStruct((2, CONFIG.img_h, CONFIG.img_w, 3),
+                               jnp.float32, sharding=one_chip)
+    compiled = _compile(lambda p, x: SW.forward_full(CONFIG, p, x),
+                        params, img)
+    text = compiled.as_text()
+    for stage, depth in enumerate(CONFIG.depths):
+        assert text.count(f"%swin.stage{stage}.") >= depth
 
 
 def _split1_stream_len():
